@@ -37,7 +37,7 @@ def main():
         "--arch", "yi_9b", "--smoke", "--steps", "10", "--mesh", "4x2",
         "--fabric", "photonic", "--batch", "8", "--seq", "64",
         "--lr", "3e-3",
-    ])
+    ])["losses"][-1]
     print(f"final loss: {loss:.4f}")
 
     print("\n=== 2. Opus phase table for the paper's Config 1 ===")

@@ -43,7 +43,7 @@ def main():
     print(f"=== phase 2: simulate node loss -> elastic restart on 2x2x2 ===")
     loss = train_main(arch_args + ["--steps", str(steps), "--mesh", "2x2x2",
                                    "--lr", "1e-3", "--ckpt", ck, "--resume",
-                                   "--plane-report"])
+                                   "--plane-report"])["losses"][-1]
     print(f"trained {steps} steps across a mesh change; final loss {loss:.4f}")
     print("(the control-plane report above replayed this job through the "
           "real Shim/Controller/RailOrchestrator stack)")

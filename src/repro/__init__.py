@@ -1,8 +1,6 @@
 """Photonic-rails reproduction package.
 
-The pure-python layers (core/, sim/, benchmarks) import no jax.  Modules
-that touch the jax mesh/shard_map API import ``repro.compat`` themselves,
-which installs forward-compat aliases for older jax versions (see
-DESIGN.md §7) — keeping the simulator and benchmark entry points free of
-jax initialization at import time.
+The pure-python layers (core/, sim/, benchmarks) import no jax, so the
+simulator and benchmark entry points start without initializing a jax
+backend.
 """

@@ -14,14 +14,17 @@ Used by the decode_32k / long_500k serve cells; validated against
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
+from repro.kernels.partition import on_mesh
 
 NEG_INF = ref.NEG_INF
 
@@ -40,8 +43,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
     k = k_ref[0, 0].astype(jnp.float32)                      # [bk, dh]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [rep, bk]
-    vmask = valid_ref[0] != 0                                # [bk]
-    s = jnp.where(vmask[None, :], s, NEG_INF)
+    vmask = valid_ref[0] != 0                                # [1, bk]
+    s = jnp.where(vmask, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -93,22 +96,34 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
                      interpret: bool = False) -> jnp.ndarray:
     """q [B,1,H,dh]; k/v_cache [B,C,KV,dh]; valid_mask [B,C] -> [B,1,H,dh].
 
-    Differentiable: grads recompute through ``ref.decode_attention``'s
-    VJP (the Pallas forward has no AD rule).
+    A capacity that ``block_k`` does not divide is padded with invalid
+    slots.  Differentiable: grads recompute through
+    ``ref.decode_attention``'s VJP (the Pallas forward has no AD rule).
     """
     b, _, h, dh = q.shape
     c = k_cache.shape[1]
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     block_k = min(block_k, c)
-    if c % block_k:
-        return ref.decode_attention(q, k_cache, v_cache, valid_mask,
-                                    scale=scale)
-    return _decode(q, k_cache, v_cache, valid_mask, scale, block_k,
-                   interpret)
+    k_cache, _ = ref._pad_to(k_cache, block_k, 1)
+    v_cache, _ = ref._pad_to(v_cache, block_k, 1)
+    vm, _ = ref._pad_to(valid_mask, block_k, 1)
+    return _decode(q, k_cache, v_cache, vm, scale, block_k, interpret)
 
 
 def _decode_fwd(q, k_cache, v_cache, valid_mask, scale, block_k,
                 interpret):
+    """``_decode_call`` under the ambient mesh (``kernels.partition``)."""
+    def spec(b, m):
+        return ((P(b, None, m, None),) * 3 + (P(b, None),),
+                P(b, None, m, None))
+    call = functools.partial(_decode_call, scale=scale, block_k=block_k,
+                             interpret=interpret)
+    return on_mesh(call, q.shape[0], math.gcd(q.shape[2], k_cache.shape[2]),
+                   spec)(q, k_cache, v_cache, valid_mask)
+
+
+def _decode_call(q, k_cache, v_cache, valid_mask, *, scale, block_k,
+                 interpret):
     b, _, h, dh = q.shape
     c, kvh = k_cache.shape[1], k_cache.shape[2]
     rep = h // kvh
@@ -117,7 +132,8 @@ def _decode_fwd(q, k_cache, v_cache, valid_mask, scale, block_k,
     qt = q.reshape(b, kvh, rep, dh)                         # [B,KV,rep,dh]
     kt = jnp.transpose(k_cache, (0, 2, 1, 3))               # [B,KV,C,dh]
     vt = jnp.transpose(v_cache, (0, 2, 1, 3))
-    vm = valid_mask.astype(jnp.int32)                       # [B,C]
+    # [B,1,C]: a (1, block_k) tile of the mask meets Mosaic's (8, 128) rule
+    vm = valid_mask.astype(jnp.int32)[:, None, :]
 
     kernel = functools.partial(_decode_kernel, scale=scale, nk=nk)
     o = pl.pallas_call(
@@ -127,7 +143,7 @@ def _decode_fwd(q, k_cache, v_cache, valid_mask, scale, block_k,
             pl.BlockSpec((1, 1, rep, dh), lambda b_, g, ik: (b_, g, 0, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, g, ik: (b_, g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, g, ik: (b_, g, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda b_, g, ik: (b_, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, g, ik: (b_, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, dh), lambda b_, g, ik: (b_, g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, dh), q.dtype),

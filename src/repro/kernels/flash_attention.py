@@ -22,20 +22,25 @@ recompute-from-lse scheme flash2 uses); a fused bwd kernel is a listed
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
+from repro.kernels.partition import on_mesh
 
 NEG_INF = ref.NEG_INF
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                scale: float, causal: bool, window: Optional[int],
-               q_offset: int, block_q: int, block_k: int, nk: int):
+               q_offset: int, block_q: int, block_k: int, nk: int,
+               kv_len: Optional[int]):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -52,6 +57,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         relevant &= k_start <= q_start + block_q - 1
     if window is not None:  # kv block entirely left of every row's window
         relevant &= k_start + block_k - 1 > q_start - window
+    if kv_len is not None:  # kv block made only of padding
+        relevant &= k_start < kv_len
 
     @pl.when(relevant)
     def _body():
@@ -66,6 +73,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= kpos <= qpos
         if window is not None:
             mask &= kpos > qpos - window
+        if kv_len is not None:
+            mask &= kpos < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]                                  # [bq, 1]
@@ -84,11 +93,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-from jax.experimental.pallas import tpu as pltpu  # noqa: E402
+def _flash_fwd2(q, k, v, **kw):
+    """``_flash_call`` under the ambient mesh (``kernels.partition``)."""
+    def spec(b, m):
+        return (P(b, m, None, None),) * 3, P(b, m, None, None)
+    return on_mesh(functools.partial(_flash_call, **kw), q.shape[0],
+                   math.gcd(q.shape[1], k.shape[1]), spec)(q, k, v)
 
 
-def _flash_fwd2(q, k, v, *, causal, window, scale, q_offset,
-                block_q, block_k, interpret):
+def _flash_call(q, k, v, *, causal, window, scale, q_offset,
+                block_q, block_k, kv_len, interpret):
     """q [B,H,Sq,dh], k/v [B,KV,Sk,dh] -> o [B,H,Sq,dh]."""
     b, h, sq, dh = q.shape
     kvh, sk = k.shape[1], k.shape[2]
@@ -97,7 +111,8 @@ def _flash_fwd2(q, k, v, *, causal, window, scale, q_offset,
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, block_q=block_q, block_k=block_k, nk=nk)
+        q_offset=q_offset, block_q=block_q, block_k=block_k, nk=nk,
+        kv_len=kv_len)
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -121,24 +136,25 @@ def _flash_fwd2(q, k, v, *, causal, window, scale, q_offset,
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, window, scale, q_offset, block_q, block_k,
-           interpret):
+           kv_len, interpret):
     return _flash_fwd2(q, k, v, causal=causal, window=window, scale=scale,
                        q_offset=q_offset, block_q=block_q, block_k=block_k,
-                       interpret=interpret)
+                       kv_len=kv_len, interpret=interpret)
 
 
 def _flash_vjp_fwd(q, k, v, causal, window, scale, q_offset, block_q,
-                   block_k, interpret):
+                   block_k, kv_len, interpret):
     out = _flash_fwd2(q, k, v, causal=causal, window=window, scale=scale,
                       q_offset=q_offset, block_q=block_q, block_k=block_k,
-                      interpret=interpret)
+                      kv_len=kv_len, interpret=interpret)
     return out, (q, k, v)
 
 
 def _flash_vjp_bwd(causal, window, scale, q_offset, block_q, block_k,
-                   interpret, res, do):
+                   kv_len, interpret, res, do):
     """Blocked flash backward via the ref VJP (recompute-from-lse)."""
     q, k, v = res  # [B,H,Sq,dh] / [B,KV,Sk,dh]
     b, h, sq, dh = q.shape
@@ -150,12 +166,13 @@ def _flash_vjp_bwd(causal, window, scale, q_offset, block_q, block_k,
     vr = jnp.transpose(v, (0, 2, 1, 3))
     out, lse = ref._mha_fwd_blocks(q5, kr, vr, causal=causal, window=window,
                                    scale=scale, q_offset=q_offset,
-                                   block_q=block_q, block_k=block_k)
+                                   block_q=block_q, block_k=block_k,
+                                   kv_valid_len=kv_len)
     do5 = jnp.transpose(do.reshape(b, kvh, rep, sq, dh), (0, 3, 1, 2, 4))
     dq, dk, dv = ref._mha_bwd_blocks(q5, kr, vr, out, lse, do5, causal=causal,
                                      window=window, scale=scale,
                                      q_offset=q_offset, block_q=block_q,
-                                     block_k=block_k)
+                                     block_k=block_k, kv_valid_len=kv_len)
     dq = jnp.transpose(dq, (0, 2, 3, 1, 4)).reshape(b, h, sq, dh)
     dk = jnp.transpose(dk, (0, 2, 1, 3))
     dv = jnp.transpose(dv, (0, 2, 1, 3))
@@ -170,18 +187,22 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, q_offset: int = 0,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False) -> jnp.ndarray:
-    """Public entry.  q [B,Sq,H,dh], k/v [B,Sk,KV,dh] -> [B,Sq,H,dh]."""
+    """Public entry.  q [B,Sq,H,dh], k/v [B,Sk,KV,dh] -> [B,Sq,H,dh].
+
+    Ragged lengths are padded to the blocks; padded keys are masked out.
+    """
     b, sq, h, dh = q.shape
     sk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:  # ragged: fall back to the oracle
-        return ref.mha(q, k, v, causal=causal, window=window, scale=scale,
-                       q_offset=q_offset)
+    q, _ = ref._pad_to(q, block_q, 1)
+    k, _ = ref._pad_to(k, block_k, 1)
+    v, _ = ref._pad_to(v, block_k, 1)
+    kv_len = sk if k.shape[1] != sk else None
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     o = _flash(qt, kt, vt, causal, window, scale, q_offset, block_q, block_k,
-               interpret)
-    return jnp.transpose(o, (0, 2, 1, 3))
+               kv_len, interpret)
+    return jnp.transpose(o, (0, 2, 1, 3))[:, :sq]

@@ -1,16 +1,19 @@
 """Jit'd dispatch wrappers for the perf-critical kernels.
 
-On TPU the Pallas kernels are used; everywhere else (this CPU container,
-and any backend without Mosaic) the blocked pure-jnp implementations from
+On TPU the Pallas kernels are used; everywhere else (a CPU host, and any
+backend without Mosaic) the blocked pure-jnp implementations from
 ``ref.py`` run — same tiling structure, same memory behaviour, so roofline
 terms derived from the dry-run match the kernel path.
 
 Set ``REPRO_KERNELS=pallas_interpret`` to force the Pallas kernels in
 interpret mode (used by the kernel tests on CPU), or ``REPRO_KERNELS=ref``
-to force the oracles even on TPU.
+to force the oracles even on TPU.  Each dispatch logs the path it took
+(``pallas``, ``interpret`` or ``ref``) on this module's logger, once per
+trace.
 """
 from __future__ import annotations
 
+import logging
 import os
 from typing import Optional
 
@@ -18,18 +21,31 @@ import jax
 
 from repro.kernels import ref
 
+log = logging.getLogger(__name__)
+
+_PATHS = {"pallas": "pallas", "pallas_interpret": "interpret", "ref": "ref"}
+
 
 def _mode() -> str:
     env = os.environ.get("REPRO_KERNELS", "auto")
-    if env in ("ref", "pallas", "pallas_interpret"):
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if env == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if env not in _PATHS:
+        raise ValueError(f"REPRO_KERNELS={env!r}: expected auto, "
+                         + ", ".join(_PATHS))
+    return env
+
+
+def _dispatch(kernel: str) -> str:
+    mode = _mode()
+    log.info("%s: %s", kernel, _PATHS[mode])
+    return mode
 
 
 def mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
         scale: Optional[float] = None, q_offset: int = 0):
     """Flash attention.  q [B,Sq,H,dh], k/v [B,Sk,KV,dh] -> [B,Sq,H,dh]."""
-    mode = _mode()
+    mode = _dispatch("flash_attention")
     if mode in ("pallas", "pallas_interpret"):
         from repro.kernels import flash_attention as fa
         return fa.flash_attention(
@@ -42,7 +58,7 @@ def mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 def decode_attention(q, k_cache, v_cache, valid_mask, *,
                      scale: Optional[float] = None):
     """Flash-decode.  q [B,1,H,dh], caches [B,C,KV,dh], valid [B,C]."""
-    mode = _mode()
+    mode = _dispatch("decode_attention")
     if mode in ("pallas", "pallas_interpret"):
         from repro.kernels import decode_attention as da
         return da.decode_attention(
@@ -53,7 +69,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
 
 def ssd(x, dt, a, b_mat, c_mat, chunk: int, h_init=None):
     """Mamba-2 SSD chunked scan (see models.ssm for shapes)."""
-    mode = _mode()
+    mode = _dispatch("ssd")
     if mode in ("pallas", "pallas_interpret"):
         from repro.kernels import ssd_scan
         return ssd_scan.ssd(x, dt, a, b_mat, c_mat, chunk, h_init=h_init,

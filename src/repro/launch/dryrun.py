@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax API aliases)
 from repro.analysis import flops as flopsa
 from repro.analysis import memmodel
 from repro.analysis.hlo_cost import corrected_cost

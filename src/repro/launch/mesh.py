@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat  # noqa: F401  (jax API aliases)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

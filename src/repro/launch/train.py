@@ -3,6 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch yi_9b --smoke \
         --steps 50 --mesh 4x2 --fabric photonic --ckpt /tmp/ck --ckpt-every 20
 
+Training runs with full rematerialization, as the dry-run's training cells
+do: at published widths the saved activations of a whole layer stack do
+not fit a chip otherwise.  ``main`` returns the per-step losses and
+cross-entropies and the final state.
+
 Features exercised here (and in examples/ + tests):
   * photonic vs eps fabric selection
   * checkpoint save/restore/reshard (restart on a DIFFERENT mesh works)
@@ -16,8 +21,8 @@ import time
 
 import jax
 
-from repro import compat  # noqa: F401  (jax API aliases)
 from repro.configs.base import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as tf
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig, synth_batch
@@ -25,8 +30,11 @@ from repro.train.optimizer import OptConfig
 from repro.train.step import TrainSetup, init_sharded_state, make_train_step
 
 
-def parse_mesh(s: str):
-    dims = tuple(int(x) for x in s.split("x"))
+def parse_mesh(s=None):
+    """``DxM`` (data x model) or ``PxDxM``; None puts every device on
+    ``data``."""
+    dims = ((jax.device_count(), 1) if s is None
+            else tuple(int(x) for x in s.split("x")))
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
     return jax.make_mesh(dims, axes,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(dims))
@@ -37,7 +45,8 @@ def main(argv=None):
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--mesh", default="4x2")
+    ap.add_argument("--mesh", default=None,
+                    help="DxM or PxDxM; default: every device on data")
     ap.add_argument("--fabric", default="photonic", choices=["photonic", "eps"])
     ap.add_argument("--hsdp", action="store_true")
     ap.add_argument("--compress", action="store_true")
@@ -56,7 +65,8 @@ def main(argv=None):
                     help="OCS reconfiguration latency for --plane-report")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    use_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke).replace(remat="full")
     mesh = parse_mesh(args.mesh)
     setup = TrainSetup(cfg=cfg, fabric=args.fabric, hsdp=args.hsdp,
                        compress_pod_grads=args.compress, accum=args.accum,
@@ -75,15 +85,20 @@ def main(argv=None):
             params, opt, ef = init_sharded_state(setup, mesh, rng)
         step_fn = jax.jit(make_train_step(setup, mesh, tpl))
 
-        t0 = time.time()
+        losses, ces = [], []
         for step in range(start, args.steps):
             batch = synth_batch(cfg, dc, step)
-            params, opt, ef, m = step_fn(params, opt, ef, batch)
-            if step % 5 == 0 or step == args.steps - 1:
-                print(f"step {step:4d} loss {float(m['loss']):.4f} "
-                      f"ce {float(m['ce']):.4f} gnorm "
-                      f"{float(m['grad_norm']):.3f} "
-                      f"({(time.time()-t0):.1f}s)", flush=True)
+            t0 = time.perf_counter()
+            params, opt, ef, m = jax.block_until_ready(
+                step_fn(params, opt, ef, batch))
+            ms = (time.perf_counter() - t0) * 1e3
+            losses.append(float(m["loss"]))
+            ces.append(float(m["ce"]))
+            print(f"step {step:4d} loss {losses[-1]:.4f} "
+                  f"ce {ces[-1]:.4f} gnorm "
+                  f"{float(m['grad_norm']):.3f} ({ms:.1f} ms"
+                  + (", compile included)" if step == start else ")"),
+                  flush=True)
             if args.ckpt and args.ckpt_every and \
                     (step + 1) % args.ckpt_every == 0:
                 ckpt.save(args.ckpt, params, opt, ef,
@@ -93,7 +108,7 @@ def main(argv=None):
             ckpt.save(args.ckpt, params, opt, ef, extra={"step": args.steps})
     if args.plane_report:
         plane_report(cfg, mesh, args.batch, args.seq, args.ocs_latency)
-    return float(m["loss"])
+    return {"losses": losses, "ce": ces, "params": params, "opt": opt}
 
 
 def plane_report(cfg, mesh, global_batch: int, seq_len: int,

@@ -11,8 +11,9 @@ Shapes
   d_inner  = expand * D;  H = d_inner / head_dim (SSD heads);  N = state_dim
   ssm head dim P = head_dim;  n_groups G shares B/C projections across heads.
 
-The perf-critical chunk kernel also exists as a Pallas kernel
-(``repro.kernels.ssd_scan``) validated against ``ssd_chunked`` here.
+``ssm_apply`` runs the chunk scan through ``repro.kernels.ops.ssd``: the
+Pallas kernel (``repro.kernels.ssd_scan``) on TPU, ``ssd_chunked`` here
+elsewhere; the two are asserted allclose in tests.
 """
 from __future__ import annotations
 
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models.layers import dense_init, rms_norm, rms_norm_init
 
 
@@ -162,8 +164,7 @@ def ssm_apply(p, x, cfg: ModelConfig, *, h_init=None):
     if pad:
         zp = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
         xin, dt, b_mat, c_mat = zp(xin), zp(dt), zp(b_mat), zp(c_mat)
-    y, _ = ssd_chunked(xin, dt, a, b_mat, c_mat, s_cfg.chunk_size,
-                       h_init=h_init)
+    y, _ = ops.ssd(xin, dt, a, b_mat, c_mat, s_cfg.chunk_size, h_init=h_init)
     y = y[:, :s] + p["d_skip"][None, None, :, None] * xin[:, :s]
     y = y.reshape(bsz, s, d_inner)
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), p["norm_w"],

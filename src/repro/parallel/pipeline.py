@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax API aliases)
 from repro.configs.base import ModelConfig
 from repro.core.fabric import ring_perm
 from repro.models import transformer as tf

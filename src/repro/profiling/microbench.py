@@ -15,9 +15,7 @@ Three case families:
 * **phase cases** — ``lm_loss`` forward, its grad step, last-only prefill
   and one-token decode on catalog configs, measured at TWO depths and
   depth-differenced so the per-layer cost is clean of embed/unembed;
-* **sharded step** — the distributed photonic train step, gracefully
-  recorded as *skipped* on hosts where
-  ``compat.supports_partial_manual()`` gates the manual-rings path.
+* **sharded step** — the distributed photonic train step.
 
 ``run_suite`` returns a :class:`TimingArtifact` with provenance (host,
 backend, jax version, kernel source hash) — commit it like a BENCH
@@ -35,7 +33,6 @@ from typing import Callable, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.analysis.calibrate import TimingArtifact, TimingRecord
 from repro.analysis.hlo_cost import corrected_cost
 from repro.configs.base import ASSIGNED_ARCHS, get_config
@@ -344,50 +341,35 @@ def phase_records(configs: Sequence[str] = DEFAULT_PHASE_CONFIGS, *,
 
 def sharded_step_records(*, repeats: int = 3, warmup: int = 1,
                          trim: int = 0) -> List[TimingRecord]:
-    """The distributed photonic train step, or a recorded skip where
-    ``compat.supports_partial_manual()`` gates the manual-rings path."""
-    if not compat.supports_partial_manual():
-        return [TimingRecord(
-            "train_step_sharded", "gated", {}, 0.0, 0.0, 0.0, 0.0, 0,
-            skipped=True,
-            skip_reason="partial-manual shard_map unsupported on this "
-                        "jaxlib/device count (repro.compat)")]
+    """The distributed photonic train step on a (n/2, 2) mesh."""
     from repro.train.step import (TrainSetup, init_sharded_state,
                                   make_train_step)
     n = jax.device_count()
     mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
     cfg = get_config("llama3_8b", smoke=True)
     setup = TrainSetup(cfg)
-    out = []
-    try:
-        with jax.set_mesh(mesh):
-            params, opt, ef = init_sharded_state(
-                setup, mesh, jax.random.PRNGKey(0))
-            tpl = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
-            step = jax.jit(make_train_step(setup, mesh, tpl))
-            ks = jax.random.split(KEY, 2)
-            batch = {"tokens": jax.random.randint(ks[0], (8, 128), 0,
-                                                  cfg.vocab_size,
-                                                  jnp.int32),
-                     "targets": jax.random.randint(ks[1], (8, 128), 0,
-                                                   cfg.vocab_size,
-                                                   jnp.int32)}
-            text = step.lower(params, opt, ef, batch).compile().as_text()
-            cc = corrected_cost(text, {"data": n // 2, "model": 2})
-            t_mean, t_min = _time(step, (params, opt, ef, batch),
-                                  repeats=repeats, warmup=warmup,
-                                  trim=trim)
-            out.append(TimingRecord(
-                "train_step_sharded", "llama3_8b_smoke",
-                {"mesh": [n // 2, 2], "batch": 8, "seq": 128},
-                float(cc.flops), float(cc.bytes_accessed), t_mean, t_min,
-                repeats))
-    except Exception as e:  # pragma: no cover - host-dependent
-        out.append(TimingRecord("train_step_sharded", "gated", {}, 0.0,
-                                0.0, 0.0, 0.0, 0, skipped=True,
-                                skip_reason=f"{type(e).__name__}: {e}"))
-    return out
+    with jax.set_mesh(mesh):
+        params, opt, ef = init_sharded_state(
+            setup, mesh, jax.random.PRNGKey(0))
+        tpl = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+        step = jax.jit(make_train_step(setup, mesh, tpl))
+        ks = jax.random.split(KEY, 2)
+        batch = {"tokens": jax.random.randint(ks[0], (8, 128), 0,
+                                              cfg.vocab_size,
+                                              jnp.int32),
+                 "targets": jax.random.randint(ks[1], (8, 128), 0,
+                                               cfg.vocab_size,
+                                               jnp.int32)}
+        text = step.lower(params, opt, ef, batch).compile().as_text()
+        cc = corrected_cost(text, {"data": n // 2, "model": 2})
+        t_mean, t_min = _time(step, (params, opt, ef, batch),
+                              repeats=repeats, warmup=warmup,
+                              trim=trim)
+    return [TimingRecord(
+        "train_step_sharded", "llama3_8b_smoke",
+        {"mesh": [n // 2, 2], "batch": 8, "seq": 128},
+        float(cc.flops), float(cc.bytes_accessed), t_mean, t_min, repeats)]
 
 
 # ---------------------------------------------------------------------------
@@ -410,7 +392,7 @@ def run_suite(*, smoke: bool = True, repeats: int = 5, warmup: int = 2,
     progress("phases: " + ", ".join(phase_configs))
     records += phase_records(phase_configs, smoke=smoke, repeats=repeats,
                              warmup=warmup, trim=trim)
-    if include_sharded:
+    if include_sharded and jax.device_count() >= 2:
         progress("sharded train step")
         records += sharded_step_records()
     provenance = {

@@ -27,7 +27,6 @@ from dataclasses import dataclass
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.fabric import Fabric
 from repro.models import transformer as tf
@@ -98,11 +97,6 @@ def make_decode_step(setup: ServeSetup, mesh, params_tpl, *,
     cfg = setup.cfg
     if setup.weight_resident:
         return _make_resident_decode_step(setup, mesh, params_tpl)
-    if not compat.supports_partial_manual():
-        import warnings
-        warnings.warn("photonic decode needs partial-manual shard_map "
-                      "(jax >= 0.5); using the GSPMD weight-resident step")
-        return _make_resident_decode_step(setup, mesh, params_tpl)
     ax = st.mesh_axes(mesh)
     model_size = ax[sh.MODEL_AXIS]
     dp_axes = st.dp_axes_of(mesh)
@@ -162,21 +156,6 @@ def make_decode_step(setup: ServeSetup, mesh, params_tpl, *,
     return step
 
 
-def _make_gspmd_prefill_step(setup: ServeSetup, mesh):
-    """GSPMD prefill: params stay NamedSharded, XLA inserts the gathers —
-    the electrical-baseline formulation of the same forward."""
-    cfg = setup.cfg
-    dp_axes = st.dp_axes_of(mesh)
-    csp = sh.make_csp(dp_axes, manual_rails=False)
-
-    def step(params, batch):
-        logits, _ = tf.lm_forward(params, batch, cfg, csp=csp,
-                                  last_only=True)
-        return logits
-
-    return step
-
-
 def _make_resident_decode_step(setup: ServeSetup, mesh, params_tpl):
     """GSPMD weight-resident decode: no per-token parameter gathers.
 
@@ -198,11 +177,6 @@ def _make_resident_decode_step(setup: ServeSetup, mesh, params_tpl):
 def make_prefill_step(setup: ServeSetup, mesh, params_tpl):
     """prefill(params, batch) -> last-token logits (forward only)."""
     cfg = setup.cfg
-    if not compat.supports_partial_manual():
-        import warnings
-        warnings.warn("photonic prefill needs partial-manual shard_map "
-                      "(jax >= 0.5); using the GSPMD prefill step")
-        return _make_gspmd_prefill_step(setup, mesh)
     ax = st.mesh_axes(mesh)
     model_size = ax[sh.MODEL_AXIS]
     dp_axes = st.dp_axes_of(mesh)

@@ -27,7 +27,9 @@ class OptConfig:
 
 
 def adamw_init(params) -> Dict[str, Any]:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    # zeros_like keeps each parameter's sharding: the moments are built
+    # shard by shard, never whole on one device
+    zeros = lambda p: jnp.zeros_like(p, jnp.float32)
     return {
         "m": jax.tree_util.tree_map(zeros, params),
         "v": jax.tree_util.tree_map(zeros, params),

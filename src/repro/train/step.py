@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.fabric import Fabric
 from repro.models import transformer as tf
@@ -198,19 +197,6 @@ def make_train_step(setup: TrainSetup, mesh, params_tpl):
     """
     if setup.fabric == "eps":
         return _make_eps_step(setup, mesh)
-    if not compat.supports_partial_manual():
-        # old jaxlib: shard_map cannot keep the model axis GSPMD-auto while
-        # the rails are manual (see repro.compat).  Run the SAME math
-        # through the GSPMD path; ring-collective coverage stays with the
-        # full-manual fabric tests.  Compression needs the manual pod sync
-        # and is unavailable here.
-        import warnings
-        warnings.warn(
-            "photonic shard_map path needs partial-manual support "
-            "(jax >= 0.5); falling back to the GSPMD (eps) train step"
-            + (" — pod-gradient compression disabled"
-               if setup.compress_pod_grads else ""))
-        return _make_eps_step(setup, mesh)
 
     cfg = setup.cfg
     ax = mesh_axes(mesh)
@@ -358,16 +344,24 @@ def state_specs(setup: TrainSetup, mesh, params_tpl):
     return specs_from_meta(params_tpl, fd, td, rails, include_model=True)
 
 
+def init_sharded_params(setup: TrainSetup, mesh, rng):
+    """Initialize the parameters directly in their production shardings:
+    each device generates only its own shards, so no device ever holds
+    the whole model."""
+    cfg = setup.cfg
+    tpl = jax.eval_shape(lambda: tf.init_lm(rng, cfg))
+    shardings = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s),
+                                       state_specs(setup, mesh, tpl))
+    return jax.jit(lambda key: tf.init_lm(key, cfg),
+                   out_shardings=shardings)(rng)
+
+
 def init_sharded_state(setup: TrainSetup, mesh, rng):
     """Initialize (params, opt, ef) placed with production shardings."""
-    cfg = setup.cfg
-    params = tf.init_lm(rng, cfg)
-    specs = state_specs(setup, mesh, params)
-    params = jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs)
+    params = init_sharded_params(setup, mesh, rng)
     opt = adamw_init(params)
     ef = {}
     if setup.hsdp and setup.compress_pod_grads:
         ef = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            lambda p: jnp.zeros_like(p, jnp.float32), params)
     return params, opt, ef

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.fabric import Fabric
 
 
@@ -85,9 +84,6 @@ def test_gather_transpose_is_reduce_scatter(fabs):
     np.testing.assert_allclose(g, g_ref, atol=1e-5)
 
 
-@pytest.mark.skipif(not compat.supports_partial_manual(),
-                    reason="partial-manual shard_map unsupported on this "
-                           "jaxlib (see repro.compat)")
 def test_hierarchical_two_axis_gather(mesh_pod):
     fab = Fabric(("pod", "data"), (2, 2), "photonic")
     x = jnp.arange(16.).reshape(16, 1)

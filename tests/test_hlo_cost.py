@@ -1,10 +1,8 @@
 """Trip-count-corrected HLO cost extraction (the roofline's data source)."""
 import jax
 import jax.numpy as jnp
-import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.analysis.hlo_cost import corrected_cost
 from repro.core.fabric import Fabric
 
@@ -53,9 +51,6 @@ def test_xla_cost_analysis_undercounts_scans():
     assert cost["flops"] < 2 * 128 ** 3 * 2       # ~1x, not 10x
 
 
-@pytest.mark.skipif(not compat.supports_partial_manual(),
-                    reason="partial-manual shard_map unsupported on this "
-                           "jaxlib (see repro.compat)")
 def test_collective_bytes_in_scan(mesh8):
     fab = Fabric(("data",), (4,), "photonic")
 

@@ -1,17 +1,19 @@
 """Per-kernel allclose vs the pure-jnp oracles, swept over shapes/dtypes.
 
-Pallas kernels run in interpret mode (CPU container; TPU is the target)."""
+Pallas kernels run in interpret mode on the CPU (TPU is the target;
+tests/test_tpu_compile.py compiles them for the chip)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.base import get_config
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as pl_decode
 from repro.kernels.flash_attention import flash_attention as pl_flash
 from repro.kernels.ssd_scan import ssd as pl_ssd
 from repro.models.attention import _repeat_kv, make_mask, sdpa
-from repro.models.ssm import ssd_chunked
+from repro.models.ssm import ssd_chunked, ssm_apply, ssm_dims, ssm_init
 
 KEY = jax.random.PRNGKey(0)
 
@@ -58,12 +60,13 @@ def test_ref_mha_grads_match_sdpa():
         np.testing.assert_allclose(a, b_, atol=1e-4)
 
 
+@pytest.mark.parametrize("s", [128, 100])  # 100: padded to the blocks
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("h,kv,dh,window", [
     (4, 4, 64, None), (8, 2, 64, None), (4, 1, 32, 48),
 ])
-def test_pallas_flash_vs_ref(dtype, h, kv, dh, window):
-    b, s = 2, 128
+def test_pallas_flash_vs_ref(dtype, h, kv, dh, window, s):
+    b = 2
     q, k, v = _qkv(b, s, s, h, kv, dh, dtype)
     got = pl_flash(q, k, v, causal=True, window=window, block_q=32,
                    block_k=32, interpret=True)
@@ -84,15 +87,19 @@ def test_pallas_flash_grad_path():
     np.testing.assert_allclose(g1, g2, atol=1e-4)
 
 
-@pytest.mark.parametrize("valid_len", [37, 100, 256])
-def test_decode_kernel_vs_ref(valid_len):
-    b, c, h, kv, dh = 2, 256, 8, 2, 64
+@pytest.mark.parametrize("c,block_k,valid_len", [
+    (256, 64, 37), (256, 64, 100), (256, 64, 256),
+    (256, 128, 200),                     # lane-aligned mask tiles
+    (300, 128, 150), (300, 128, 300),    # ragged: padded with invalid slots
+])
+def test_decode_kernel_vs_ref(c, block_k, valid_len):
+    b, h, kv, dh = 2, 8, 2, 64
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
     kc = jax.random.normal(ks[1], (b, c, kv, dh)) * 0.5
     vc = jax.random.normal(ks[2], (b, c, kv, dh)) * 0.5
     valid = (jnp.arange(c) < valid_len)[None, :].repeat(b, 0)
-    got = pl_decode(q, kc, vc, valid, block_k=64, interpret=True)
+    got = pl_decode(q, kc, vc, valid, block_k=block_k, interpret=True)
     want = ref.decode_attention(q, kc, vc, valid, block_k=64)
     np.testing.assert_allclose(got, want, atol=1e-5)
 
@@ -128,17 +135,19 @@ def test_ssd_chunked_vs_naive(chunk, g):
     np.testing.assert_allclose(h_c, h_naive, atol=1e-4)
 
 
+@pytest.mark.parametrize("with_h0", [False, True])  # carried-in state
 @pytest.mark.parametrize("chunk", [8, 16, 32])
-def test_pallas_ssd_vs_chunked(chunk):
+def test_pallas_ssd_vs_chunked(chunk, with_h0):
     b, s, h, p, g, n = 2, 64, 4, 16, 2, 8
-    ks = jax.random.split(KEY, 5)
+    ks = jax.random.split(KEY, 6)
     x = jax.random.normal(ks[0], (b, s, h, p))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
     a = -jnp.exp(jax.random.normal(ks[2], (h,)))
     bm = jax.random.normal(ks[3], (b, s, g, n))
     cm = jax.random.normal(ks[4], (b, s, g, n))
-    y_p, st_p = pl_ssd(x, dt, a, bm, cm, chunk, interpret=True)
-    y_r, st_r = ssd_chunked(x, dt, a, bm, cm, chunk)
+    h0 = jax.random.normal(ks[5], (b, h, p, n)) if with_h0 else None
+    y_p, st_p = pl_ssd(x, dt, a, bm, cm, chunk, h_init=h0, interpret=True)
+    y_r, st_r = ssd_chunked(x, dt, a, bm, cm, chunk, h_init=h0)
     np.testing.assert_allclose(y_p, y_r, atol=5e-4)
     np.testing.assert_allclose(st_p, st_r, atol=5e-4)
 
@@ -214,3 +223,20 @@ def test_pallas_decode_grads_match_ref():
         q_, k_, v_, valid)), argnums=(0, 1, 2))(q, kc, vc)
     for got, want in zip(g1, g2):
         np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("s,with_h0", [(24, False), (20, False), (24, True)])
+def test_ssm_apply_kernel_path_matches_oracle(monkeypatch, s, with_h0):
+    """``ssm_apply`` through ``ops.ssd`` on the Pallas kernel (interpret
+    mode) equals the jnp oracle path; s=20 is padded to the chunk."""
+    cfg = get_config("mamba2_370m", smoke=True).replace(dtype="float32")
+    ks = jax.random.split(KEY, 3)
+    p = ssm_init(ks[0], cfg, jnp.float32)
+    x = jax.random.normal(ks[1], (2, s, cfg.d_model))
+    _, h, pdim, n = ssm_dims(cfg)
+    h0 = jax.random.normal(ks[2], (2, h, pdim, n)) if with_h0 else None
+    monkeypatch.setenv("REPRO_KERNELS", "ref")
+    want = ssm_apply(p, x, cfg, h_init=h0)
+    monkeypatch.setenv("REPRO_KERNELS", "pallas_interpret")
+    got = ssm_apply(p, x, cfg, h_init=h0)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.base import get_config
 from repro.models import transformer as T
 from repro.serve.step import (ServeSetup, init_serve_state, make_decode_step,
@@ -69,10 +68,6 @@ def test_context_sharded_decode(mesh8, toks, params_ref):
     np.testing.assert_allclose(jnp.stack(outs, 1), ref, atol=1e-4)
 
 
-@pytest.mark.skipif(not compat.supports_partial_manual(),
-                    reason="old XLA SPMD partitioner miscompiles the "
-                           "FSDPxTP-sharded SSM decode, and the manual "
-                           "path needs partial-manual shard_map")
 def test_context_sharded_ssm_decode(mesh8, toks):
     cfg = get_config("mamba2_370m", smoke=True).replace(dtype="float32")
     params_ref = T.init_lm(RNG, cfg)

@@ -11,7 +11,7 @@ def test_train_driver_end_to_end():
         "--arch", "yi_9b", "--smoke", "--steps", "6", "--mesh", "4x2",
         "--fabric", "photonic", "--batch", "8", "--seq", "32",
         "--lr", "3e-3",
-    ])
+    ])["losses"][-1]
     assert loss < 7.0
 
 
@@ -22,7 +22,7 @@ def test_train_restart_is_deterministic(tmp_path):
     full = train_main([
         "--arch", "yi_9b", "--smoke", "--steps", "8", "--mesh", "4x2",
         "--batch", "8", "--seq", "32", "--lr", "1e-3",
-    ])
+    ])["losses"][-1]
     train_main([
         "--arch", "yi_9b", "--smoke", "--steps", "4", "--mesh", "4x2",
         "--batch", "8", "--seq", "32", "--lr", "1e-3",
@@ -32,7 +32,7 @@ def test_train_restart_is_deterministic(tmp_path):
         "--arch", "yi_9b", "--smoke", "--steps", "8", "--mesh", "4x2",
         "--batch", "8", "--seq", "32", "--lr", "1e-3",
         "--ckpt", ck, "--resume",
-    ])
+    ])["losses"][-1]
     assert abs(full - resumed) < 1e-4
 
 
@@ -41,7 +41,7 @@ def test_hsdp_compressed_training_converges():
         "--arch", "yi_9b", "--smoke", "--steps", "6", "--mesh", "2x2x2",
         "--hsdp", "--compress", "--batch", "8", "--seq", "32",
         "--lr", "3e-3",
-    ])
+    ])["losses"][-1]
     assert loss < 7.0
 
 

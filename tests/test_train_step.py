@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs.base import get_config
 from repro.models import transformer as T
 from repro.train.checkpoint import restore, save
@@ -85,9 +84,6 @@ def test_loss_decreases_over_steps(mesh8, tpl):
     assert losses[-1] < losses[0] - 0.2
 
 
-@pytest.mark.skipif(not compat.supports_partial_manual(),
-                    reason="compressed pod AllReduce needs partial-manual "
-                           "shard_map (see repro.compat)")
 def test_error_feedback_accumulates(mesh_pod, batch, tpl):
     with jax.set_mesh(mesh_pod):
         setup = TrainSetup(cfg=CFG, hsdp=True, compress_pod_grads=True)
@@ -136,3 +132,23 @@ def test_moe_arch_through_distributed_step(mesh8, batch):
     # per-device aux-balance loss is a different (nonlinear) partition of
     # the same quantity — small tolerance (DESIGN.md §Arch-applicability)
     assert abs(float(m["loss"]) - float(loss_ref)) < 1e-2
+
+
+@pytest.mark.parametrize("fabric", ["photonic", "eps"])
+def test_kernel_path_under_mesh_matches_ref(mesh8, batch, monkeypatch,
+                                            fabric):
+    """The Pallas SSD kernel (interpret mode) inside the distributed step:
+    ops wraps it in a shard_map over the mesh axes not yet manual, with
+    heads split over `model`; the step must equal the oracle path."""
+    cfg = get_config("mamba2_370m", smoke=True).replace(dtype="float32")
+    tpl = jax.eval_shape(lambda: T.init_lm(RNG, cfg))
+    out = {}
+    for mode in ("ref", "pallas_interpret"):
+        monkeypatch.setenv("REPRO_KERNELS", mode)
+        with jax.set_mesh(mesh8):
+            setup = TrainSetup(cfg=cfg, fabric=fabric)
+            params, opt, ef = init_sharded_state(setup, mesh8, RNG)
+            step = jax.jit(make_train_step(setup, mesh8, tpl))
+            _, _, _, m = step(params, opt, ef, batch)
+        out[mode] = (float(m["loss"]), float(m["grad_norm"]))
+    assert out["pallas_interpret"] == pytest.approx(out["ref"], rel=1e-4)
