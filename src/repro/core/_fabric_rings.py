@@ -38,6 +38,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import scope
+
 
 def ring_perm(n: int, shift: int = 1):
     return [(i, (i + shift) % n) for i in range(n)]
@@ -90,6 +92,14 @@ def ring_all_gather(x, axis_name: str, axis_size: int, axis: int = 0,
     bytes halve (§Perf H3; the unidirectional ring is the paper-faithful
     baseline, which leaves the second link dark).
     """
+    with scope("ring_gather"):
+        return _ring_all_gather(x, axis_name, axis_size, axis, bidirectional)
+
+
+def _ring_all_gather(x, axis_name: str, axis_size: int, axis: int = 0,
+                     bidirectional: bool = False):
+    """``ring_all_gather`` outside any scope, for the collectives built
+    from it (each under its own scope)."""
     if axis_size == 1:
         return x
     if bidirectional and x.shape[axis] % 2 == 0 and axis_size > 2:
@@ -110,12 +120,17 @@ def ring_reduce_scatter(x, axis_name: str, axis_size: int, axis: int = 0):
     x full along dim ``axis`` -> summed shard (1/n size).  Deriving it as a
     transpose guarantees AG/RS are exact adjoints (gradient consistency).
     """
+    with scope("ring_scatter"):
+        return _ring_reduce_scatter(x, axis_name, axis_size, axis)
+
+
+def _ring_reduce_scatter(x, axis_name: str, axis_size: int, axis: int = 0):
     if axis_size == 1:
         return x
     shard_shape = list(x.shape)
     assert shard_shape[axis] % axis_size == 0, (x.shape, axis, axis_size)
     shard_shape[axis] //= axis_size
-    f = functools.partial(ring_all_gather, axis_name=axis_name,
+    f = functools.partial(_ring_all_gather, axis_name=axis_name,
                           axis_size=axis_size, axis=axis)
     (out,) = jax.linear_transpose(
         f, jax.ShapeDtypeStruct(tuple(shard_shape), x.dtype))(x)
@@ -126,15 +141,16 @@ def ring_all_reduce(x, axis_name: str, axis_size: int):
     """Ring AllReduce = flat ReduceScatter + AllGather (bandwidth-optimal)."""
     if axis_size == 1:
         return x
-    flat = x.reshape(-1)
-    pad = (-flat.shape[0]) % axis_size
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    shard = ring_reduce_scatter(flat, axis_name, axis_size)
-    full = ring_all_gather(shard, axis_name, axis_size)
-    if pad:
-        full = full[:-pad]
-    return full.reshape(x.shape)
+    with scope("ring_all_reduce"):
+        flat = x.reshape(-1)
+        pad = (-flat.shape[0]) % axis_size
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        shard = _ring_reduce_scatter(flat, axis_name, axis_size)
+        full = _ring_all_gather(shard, axis_name, axis_size)
+        if pad:
+            full = full[:-pad]
+        return full.reshape(x.shape)
 
 
 def ring_all_to_all(xstack, axis_name: str, axis_size: int):
@@ -146,6 +162,11 @@ def ring_all_to_all(xstack, axis_name: str, axis_size: int):
     """
     if axis_size == 1:
         return xstack
+    with scope("ring_all_to_all"):
+        return _ring_all_to_all(xstack, axis_name, axis_size)
+
+
+def _ring_all_to_all(xstack, axis_name: str, axis_size: int):
     idx = jax.lax.axis_index(axis_name)
     perm = ring_perm(axis_size)
     own = jax.lax.dynamic_index_in_dim(xstack, idx, 0)
@@ -196,21 +217,27 @@ class Fabric:
 
     # -- AllGather: minor axis first, so flat shard index is major-first --
     def all_gather(self, x, axis: int = 0):
+        if self.kind == "photonic":
+            with scope("ring_gather"):
+                return self._ring_gather(x, axis)
+        for name in reversed(self.axes):
+            x = jax.lax.all_gather(x, name, axis=axis, tiled=True)
+        return x
+
+    def _ring_gather(self, x, axis: int):
         for name, size in zip(reversed(self.axes), reversed(self.sizes)):
-            if self.kind == "photonic":
-                x = ring_all_gather(x, name, size, axis=axis,
-                                    bidirectional=self.bidirectional)
-            else:
-                x = jax.lax.all_gather(x, name, axis=axis, tiled=True)
+            x = _ring_all_gather(x, name, size, axis=axis,
+                                 bidirectional=self.bidirectional)
         return x
 
     def reduce_scatter(self, x, axis: int = 0):
         if self.kind == "photonic":
             shard_shape = list(x.shape)
             shard_shape[axis] //= self.n_shards
-            f = functools.partial(self.all_gather, axis=axis)
-            (out,) = jax.linear_transpose(
-                f, jax.ShapeDtypeStruct(tuple(shard_shape), x.dtype))(x)
+            f = functools.partial(self._ring_gather, axis=axis)
+            with scope("ring_scatter"):
+                (out,) = jax.linear_transpose(
+                    f, jax.ShapeDtypeStruct(tuple(shard_shape), x.dtype))(x)
             return out
         for name in self.axes:  # major-to-minor (transpose order)
             x = jax.lax.psum_scatter(x, name, scatter_dimension=axis,

@@ -29,6 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.partition import on_mesh
+from repro.scopes import scope
 
 NEG_INF = -1e30
 
@@ -97,12 +98,13 @@ def _ssd_vjp_bwd(chunk, interpret, res, g):
     # pallas_call has no AD rule: recompute through the jnp oracle, whose
     # VJP is exact for the same math (tests assert fwd allclose)
     from repro.models.ssm import ssd_chunked
-    outs, vjp = jax.vjp(
-        lambda x_, dt_, a_, b_, c_, h_: ssd_chunked(x_, dt_, a_, b_, c_,
-                                                    chunk, h_init=h_),
-        *res)
-    g = tuple(gg.astype(oo.dtype) for gg, oo in zip(g, outs))
-    return vjp(g)
+    with scope("ssd_bwd"):
+        outs, vjp = jax.vjp(
+            lambda x_, dt_, a_, b_, c_, h_: ssd_chunked(x_, dt_, a_, b_, c_,
+                                                        chunk, h_init=h_),
+            *res)
+        g = tuple(gg.astype(oo.dtype) for gg, oo in zip(g, outs))
+        return vjp(g)
 
 
 _ssd.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)

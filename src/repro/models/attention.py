@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init, rope_apply
+from repro.scopes import scope
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -163,31 +164,33 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     q = rope_apply(q, posv[None], cfg.rope_theta)
     k_new = rope_apply(k_new, posv[None], cfg.rope_theta)
 
-    if ctx is not None:  # context-parallel: write only if this shard owns pos
-        slot_local = (pos - ctx["offset"]).astype(jnp.int32)
-        owned = (slot_local >= 0) & (slot_local < capacity)
-        safe = jnp.clip(slot_local, 0, capacity - 1)
-        upd = lambda buf, val: jnp.where(
-            owned, jax.lax.dynamic_update_slice_in_dim(
-                buf, val.astype(buf.dtype), safe, axis=1), buf)
-        k_cache = upd(cache["k"], k_new)
-        v_cache = upd(cache["v"], v_new)
-        slot_pos = jnp.where(
-            owned, jax.lax.dynamic_update_slice_in_dim(
-                cache["slot_pos"], posv, safe, axis=0), cache["slot_pos"])
-    else:
-        slot = jnp.where(window is None, pos, pos % capacity).astype(jnp.int32)
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-        slot_pos = jax.lax.dynamic_update_slice_in_dim(
-            cache["slot_pos"], posv, slot, axis=0)
-    new_cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
+    with scope("kv_cache"):
+        if ctx is not None:  # context-parallel: write only if this shard owns pos
+            slot_local = (pos - ctx["offset"]).astype(jnp.int32)
+            owned = (slot_local >= 0) & (slot_local < capacity)
+            safe = jnp.clip(slot_local, 0, capacity - 1)
+            upd = lambda buf, val: jnp.where(
+                owned, jax.lax.dynamic_update_slice_in_dim(
+                    buf, val.astype(buf.dtype), safe, axis=1), buf)
+            k_cache = upd(cache["k"], k_new)
+            v_cache = upd(cache["v"], v_new)
+            slot_pos = jnp.where(
+                owned, jax.lax.dynamic_update_slice_in_dim(
+                    cache["slot_pos"], posv, safe, axis=0), cache["slot_pos"])
+        else:
+            slot = jnp.where(window is None, pos,
+                             pos % capacity).astype(jnp.int32)
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+            slot_pos = jax.lax.dynamic_update_slice_in_dim(
+                cache["slot_pos"], posv, slot, axis=0)
+        new_cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
 
-    valid = (slot_pos >= 0) & (slot_pos <= pos)
-    if window is not None:
-        valid &= slot_pos > pos - window
+        valid = (slot_pos >= 0) & (slot_pos <= pos)
+        if window is not None:
+            valid &= slot_pos > pos - window
 
     if ctx is not None:
         from repro.kernels import ref as kref

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.layers import dense_init
+from repro.scopes import scope
 
 
 def moe_capacity(moe: MoEConfig, tokens_per_group: int) -> int:
@@ -171,33 +172,38 @@ def moe_apply(p, x, cfg: ModelConfig, *, rng: Optional[jax.Array] = None,
     moe = cfg.moe
     gdim, tdim, d = x.shape
     capacity = moe_capacity(moe, tdim)
-    logits = jnp.einsum("gtd,de->gte", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    gates, idx, probs = router_topk(logits, moe, rng)
-    aux = load_balance_loss(probs, idx, moe)
-    pos = choice_positions(idx, moe.n_experts)
-    fits = pos < capacity
+    with scope("moe_route"):
+        logits = jnp.einsum("gtd,de->gte", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        gates, idx, probs = router_topk(logits, moe, rng)
+        aux = load_balance_loss(probs, idx, moe)
+        pos = choice_positions(idx, moe.n_experts)
+        fits = pos < capacity
 
-    buf = scatter_dispatch(x, idx, pos, fits, moe.n_experts, capacity)
-    if csp is not None:
-        buf = csp(buf, "groups", "experts", None, None)
-    if ep_axis is not None:
-        # manual EP: exchange expert shards over the scale-up axis.
-        buf = jax.lax.all_to_all(buf, ep_axis, split_axis=1, concat_axis=2,
-                                 tiled=True)
-    e_eff = buf.shape[1]
-    ebuf = jnp.transpose(buf, (1, 0, 2, 3)).reshape(e_eff, -1, d)
-    h = _expert_ffn({k_: v for k_, v in p.items() if k_.startswith("w_")},
-                    ebuf, cfg.mlp_act)
-    h = h.reshape(e_eff, gdim, -1, d).transpose(1, 0, 2, 3)  # [G,E',C',D]
-    if ep_axis is not None:
-        h = jax.lax.all_to_all(h, ep_axis, split_axis=2, concat_axis=1,
-                               tiled=True)
-    if csp is not None:
-        h = csp(h, "groups", "experts", None, None)
-    y = gather_combine(h, idx, pos, fits, gates).astype(x.dtype)
+    with scope("moe_dispatch"):
+        buf = scatter_dispatch(x, idx, pos, fits, moe.n_experts, capacity)
+        if csp is not None:
+            buf = csp(buf, "groups", "experts", None, None)
+        if ep_axis is not None:
+            # manual EP: exchange expert shards over the scale-up axis.
+            buf = jax.lax.all_to_all(buf, ep_axis, split_axis=1,
+                                     concat_axis=2, tiled=True)
+        e_eff = buf.shape[1]
+        ebuf = jnp.transpose(buf, (1, 0, 2, 3)).reshape(e_eff, -1, d)
+    with scope("moe_experts"):
+        h = _expert_ffn({k_: v for k_, v in p.items()
+                         if k_.startswith("w_")}, ebuf, cfg.mlp_act)
+    with scope("moe_combine"):
+        h = h.reshape(e_eff, gdim, -1, d).transpose(1, 0, 2, 3)  # [G,E',C',D]
+        if ep_axis is not None:
+            h = jax.lax.all_to_all(h, ep_axis, split_axis=2, concat_axis=1,
+                                   tiled=True)
+        if csp is not None:
+            h = csp(h, "groups", "experts", None, None)
+        y = gather_combine(h, idx, pos, fits, gates).astype(x.dtype)
 
     if moe.n_shared_experts:
         from repro.models.layers import mlp_apply
-        y = y + mlp_apply(p["shared"], x, cfg.mlp_act)
+        with scope("moe_experts"):
+            y = y + mlp_apply(p["shared"], x, cfg.mlp_act)
     return y, aux

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models.layers import dense_init, rms_norm, rms_norm_init
+from repro.scopes import scope
 
 
 def ssm_dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
@@ -146,6 +147,11 @@ def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int, h_init=None):
 
 def ssm_apply(p, x, cfg: ModelConfig, *, h_init=None):
     """Full-sequence Mamba-2 block (train/prefill). x [B,S,D] -> [B,S,D]."""
+    with scope("ssm"):
+        return _ssm_apply(p, x, cfg, h_init)
+
+
+def _ssm_apply(p, x, cfg: ModelConfig, h_init):
     s_cfg = cfg.ssm
     d_inner, h, pdim, n = ssm_dims(cfg)
     g = s_cfg.n_groups
@@ -189,6 +195,11 @@ def init_ssm_cache(cfg: ModelConfig, batch: int):
 
 def ssm_decode(p, x, cache, cfg: ModelConfig):
     """Single-token recurrent step. x [B,1,D] -> (y [B,1,D], new_cache)."""
+    with scope("ssm"):
+        return _ssm_decode(p, x, cache, cfg)
+
+
+def _ssm_decode(p, x, cache, cfg: ModelConfig):
     s_cfg = cfg.ssm
     d_inner, h, pdim, n = ssm_dims(cfg)
     g = s_cfg.n_groups

@@ -28,6 +28,7 @@ from repro.models import ssm as ssm_mod
 from repro.models.layers import (cross_entropy, dense_init, mlp_apply,
                                  mlp_init, padded_vocab, rms_norm,
                                  rms_norm_init)
+from repro.scopes import scope
 
 ParamFn = Optional[Callable[[Any], Any]]
 
@@ -185,18 +186,21 @@ def stack_apply(layers, x, positions, cfg: ModelConfig, *, causal: bool = True,
     specs = period_spec(cfg)
 
     def body(carry, per_params):
-        h = carry
-        pp = layer_param_fn(per_params) if layer_param_fn else per_params
-        aux = jnp.zeros((), jnp.float32)
-        for pos, spec in enumerate(specs):
-            h, a = _apply_sublayer(pp[pos], h, positions, cfg, spec,
-                                   causal=causal, mask=mask, enc_out=enc_out,
-                                   csp=csp, prefix_len=prefix_len)
-            aux = aux + a
-        return h, aux
+        with scope("layer"):
+            h = carry
+            pp = layer_param_fn(per_params) if layer_param_fn else per_params
+            aux = jnp.zeros((), jnp.float32)
+            for pos, spec in enumerate(specs):
+                h, a = _apply_sublayer(pp[pos], h, positions, cfg, spec,
+                                       causal=causal, mask=mask,
+                                       enc_out=enc_out, csp=csp,
+                                       prefix_len=prefix_len)
+                aux = aux + a
+            return h, aux
 
     body = _remat_wrap(body, cfg.remat)
-    x, auxs = jax.lax.scan(body, x, layers)
+    with scope("layer_scan"):
+        x, auxs = jax.lax.scan(body, x, layers)
     return x, jnp.sum(auxs)
 
 
@@ -206,7 +210,8 @@ def stack_apply(layers, x, positions, cfg: ModelConfig, *, causal: bool = True,
 
 
 def _embed_tokens(params, tokens, cfg: ModelConfig):
-    return params["embed"][tokens]
+    with scope("embed"):
+        return params["embed"][tokens]
 
 
 def _unembed(params, x, cfg: ModelConfig, csp=None):
@@ -267,14 +272,15 @@ def lm_forward(params, batch, cfg: ModelConfig, *,
     x, aux = stack_apply(params["layers"], x, positions, cfg, causal=True,
                          enc_out=enc_out, layer_param_fn=layer_param_fn,
                          csp=csp, prefix_len=n_prefix)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if n_prefix:
-        x = x[:, n_prefix:]
-    if last_only:
-        x = x[:, -1:]
-    logits = _unembed(params, x, cfg, csp=csp)
-    if csp is not None:
-        logits = csp(logits, "batch", None, "vocab")
+    with scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if n_prefix:
+            x = x[:, n_prefix:]
+        if last_only:
+            x = x[:, -1:]
+        logits = _unembed(params, x, cfg, csp=csp)
+        if csp is not None:
+            logits = csp(logits, "batch", None, "vocab")
     return logits, aux
 
 
@@ -285,7 +291,8 @@ def lm_loss(params, batch, cfg: ModelConfig, *, layer_param_fn: ParamFn = None,
     logits, aux = lm_forward(params, batch, cfg,
                              layer_param_fn=layer_param_fn,
                              layer_param_fn_enc=layer_param_fn_enc, csp=csp)
-    loss, ce = cross_entropy(logits, batch["targets"], cfg.vocab_size)
+    with scope("lm_head"):
+        loss, ce = cross_entropy(logits, batch["targets"], cfg.vocab_size)
     loss = loss + aux_weight * aux
     return loss, {"ce": ce, "moe_aux": aux}
 
@@ -339,6 +346,10 @@ def decode_step(params, state, token, pos, cfg: ModelConfig, *,
     specs = period_spec(cfg)
 
     def body(carry, xs):
+        with scope("layer"):
+            return layer(carry, xs)
+
+    def layer(carry, xs):
         h = carry
         if cross_state is not None:
             per_params, per_cache, per_cross = xs
@@ -375,9 +386,11 @@ def decode_step(params, state, token, pos, cfg: ModelConfig, *,
 
     xs = (params["layers"], state) if cross_state is None else \
         (params["layers"], state, cross_state)
-    x, new_state = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(params, x, cfg)
+    with scope("layer_scan"):
+        x, new_state = jax.lax.scan(body, x, xs)
+    with scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _unembed(params, x, cfg)
     return logits, new_state
 
 

@@ -14,6 +14,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro.scopes import scope
+
 
 @dataclass(frozen=True)
 class OptConfig:
@@ -50,6 +52,11 @@ def global_norm(grads):
 
 def adamw_update(params, grads, opt, cfg: OptConfig):
     """Returns (new_params, new_opt, metrics)."""
+    with scope("adamw"):
+        return _adamw_update(params, grads, opt, cfg)
+
+
+def _adamw_update(params, grads, opt, cfg: OptConfig):
     step = opt["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
