@@ -223,7 +223,7 @@ def kernel_phase(*, flash: tuple, decode: tuple, ssd: tuple,
             lambda q, k, v, m: da.decode_attention(q, k, v, m,
                                                    interpret=interpret),
             ref.decode_attention, normal((b, 1, h, dh), bf16),
-            normal((b, c, kv, dh), bf16), normal((b, c, kv, dh), bf16), mask)
+            normal((b, kv, dh, c), bf16), normal((b, kv, dh, c), bf16), mask)
 
     b, s, h, p, g, n, chunk = ssd
     dt = jax.nn.softplus(normal((b, s, h)) - 4.0)     # mamba2's dt range

@@ -60,7 +60,11 @@ def mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 
 def decode_attention(q, k_cache, v_cache, valid_mask, *,
                      scale: Optional[float] = None):
-    """Flash-decode.  q [B,1,H,dh], caches [B,C,KV,dh], valid [B,C]."""
+    """Flash-decode.  q [B,1,H,dh], caches [B,KV,dh,C], valid [B,C].
+
+    The cache position is minor, as the decode cache stores it, so the
+    kernel reads the cache with no relayout.
+    """
     mode = _dispatch("decode_attention")
     with scope("attn_decode"):
         if mode in ("pallas", "pallas_interpret"):

@@ -11,6 +11,8 @@ these functions in tests.
 Conventions
   q        [B, Sq, H, dh]
   k, v     [B, Sk, KV, dh]        (GQA: H = KV * rep)
+  k/v_cache [B, KV, dh, C]        decode caches, the position minor: lane-
+                                  dense as stored, read with no relayout
   window   sliding-window size (None = unlimited); causal masking optional
   q_offset absolute position of q[0] (decode/chunked prefill)
 """
@@ -263,30 +265,32 @@ def mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 def decode_attention(q, k_cache, v_cache, valid_mask, *,
                      scale: Optional[float] = None,
                      block_k: int = 1024, return_stats: bool = False):
-    """q [B,1,H,dh]; k/v_cache [B,C,KV,dh]; valid_mask [B,C] bool.
+    """q [B,1,H,dh]; k/v_cache [B,KV,dh,C]; valid_mask [B,C] bool.
 
-    Blocked flash-decode over the cache dimension.  With
-    ``return_stats=True`` returns (acc [B,KV,R,dh], m [B,KV,R], l [B,KV,R])
-    *unnormalized* partials, mergeable across cache shards (context-parallel
-    decode: the merge is flash-decoding's split-K combine).
+    The cache position is the minor axis, as the decode cache stores it
+    (``models.attention.init_kv_cache``).  Blocked flash-decode over the
+    cache dimension.  With ``return_stats=True`` returns
+    (acc [B,KV,R,dh], m [B,KV,R], l [B,KV,R]) *unnormalized* partials,
+    mergeable across cache shards split along the last axis
+    (context-parallel decode: the merge is flash-decoding's split-K
+    combine).
     """
     b, _, h, dh = q.shape
-    c = k_cache.shape[1]
-    kvh = k_cache.shape[2]
+    kvh, c = k_cache.shape[1], k_cache.shape[3]
     rep = h // kvh
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     block_k = min(block_k, c)
-    k_cache, c0 = _pad_to(k_cache, block_k, 1)
-    v_cache, _ = _pad_to(v_cache, block_k, 1)
+    k_cache, c0 = _pad_to(k_cache, block_k, 3)
+    v_cache, _ = _pad_to(v_cache, block_k, 3)
     vm, _ = _pad_to(valid_mask, block_k, 1)
-    nk = k_cache.shape[1] // block_k
+    nk = k_cache.shape[3] // block_k
     f32 = jnp.float32
     qr = q.reshape(b, kvh, rep, dh)
 
     def step(carry, inp):
         m, l, acc = carry
-        kblk, vblk, mblk = inp  # [B,bk,KV,dh],[B,bk,KV,dh],[B,bk]
-        s = jnp.einsum("bgrd,bkgd->bgrk", qr, kblk,
+        kblk, vblk, mblk = inp  # [B,KV,dh,bk],[B,KV,dh,bk],[B,bk]
+        s = jnp.einsum("bgrd,bgdk->bgrk", qr, kblk,
                        preferred_element_type=f32) * scale
         s = jnp.where(mblk[:, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, -1))
@@ -294,11 +298,11 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, -1)
         acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bgrk,bkgd->bgrd", p, vblk.astype(f32))
+            "bgrk,bgdk->bgrd", p, vblk.astype(f32))
         return (m_new, l_new, acc_new), None
 
-    kb = jnp.moveaxis(k_cache.reshape(b, nk, block_k, kvh, dh), 1, 0)
-    vb = jnp.moveaxis(v_cache.reshape(b, nk, block_k, kvh, dh), 1, 0)
+    kb = jnp.moveaxis(k_cache.reshape(b, kvh, dh, nk, block_k), 3, 0)
+    vb = jnp.moveaxis(v_cache.reshape(b, kvh, dh, nk, block_k), 3, 0)
     mb = jnp.moveaxis(vm.reshape(b, nk, block_k), 1, 0)
     init = (jnp.full((b, kvh, rep), NEG_INF, f32),
             jnp.zeros((b, kvh, rep), f32),

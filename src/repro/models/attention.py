@@ -126,10 +126,17 @@ def attention(p, x, positions, cfg: ModelConfig, *, causal: bool = True,
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype):
+    """k/v [B, KV, dh, C] with the cache position minor; slot_pos [C].
+
+    The position is the longest axis, so as the minor one it fills the
+    TPU's 128 lanes with no padding, and it is the layout the stacked
+    cache is stored in and the flash-decode kernel reads: no relayout of
+    the cache in the layer scan or around the kernel.
+    """
     dh = cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, capacity, cfg.n_kv_heads, dh), dtype),
-        "v": jnp.zeros((batch, capacity, cfg.n_kv_heads, dh), dtype),
+        "k": jnp.zeros((batch, cfg.n_kv_heads, dh, capacity), dtype),
+        "v": jnp.zeros((batch, cfg.n_kv_heads, dh, capacity), dtype),
         # absolute position stored in each slot; -1 => empty
         "slot_pos": jnp.full((capacity,), -1, jnp.int32),
     }
@@ -142,6 +149,8 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     """One-token attention. x [B,1,D]; pos scalar int32 (absolute position).
 
     Full cache: slot = pos.  SWA ring cache: slot = pos % capacity.
+    The cache is ``init_kv_cache``'s [B,KV,dh,C]: the new token's K and V
+    are written as [B,KV,dh,1] at the slot along the last axis.
     ctx = {"fabric": Fabric, "offset": int32} enables context-parallel
     decode: the cache holds only this rail shard's slot range; partial
     flash-decode stats are merged across shards (split-K combine).  The
@@ -156,13 +165,15 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
         out = sdpa(q, k, v)
         return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), cache
 
-    capacity = cache["k"].shape[1]
+    capacity = cache["k"].shape[3]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     posv = jnp.reshape(pos, (1,)).astype(jnp.int32)
     q = rope_apply(q, posv[None], cfg.rope_theta)
     k_new = rope_apply(k_new, posv[None], cfg.rope_theta)
+    # [B,1,KV,dh] -> the cache's [B,KV,dh,1]
+    k_new, v_new = (jnp.moveaxis(t, 1, 3) for t in (k_new, v_new))
 
     with scope("kv_cache"):
         if ctx is not None:  # context-parallel: write only if this shard owns pos
@@ -171,7 +182,7 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
             safe = jnp.clip(slot_local, 0, capacity - 1)
             upd = lambda buf, val: jnp.where(
                 owned, jax.lax.dynamic_update_slice_in_dim(
-                    buf, val.astype(buf.dtype), safe, axis=1), buf)
+                    buf, val.astype(buf.dtype), safe, axis=3), buf)
             k_cache = upd(cache["k"], k_new)
             v_cache = upd(cache["v"], v_new)
             slot_pos = jnp.where(
@@ -181,9 +192,9 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
             slot = jnp.where(window is None, pos,
                              pos % capacity).astype(jnp.int32)
             k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=3)
             v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=3)
             slot_pos = jax.lax.dynamic_update_slice_in_dim(
                 cache["slot_pos"], posv, slot, axis=0)
         new_cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
@@ -209,9 +220,9 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
         from repro.kernels import ops
         vm = jnp.broadcast_to(valid[None, :], (q.shape[0], capacity))
         out = ops.decode_attention(q, k_cache, v_cache, vm)
-    else:
-        k = _repeat_kv(k_cache, cfg.n_heads)
-        v = _repeat_kv(v_cache, cfg.n_heads)
+    else:  # sdpa's [B,C,H,dh], made in the one copy the repeat makes
+        k = _repeat_kv(jnp.moveaxis(k_cache, 3, 1), cfg.n_heads)
+        v = _repeat_kv(jnp.moveaxis(v_cache, 3, 1), cfg.n_heads)
         mask = valid[None, None, None, :]  # [1,1,1,capacity]
         out = sdpa(q, k, v, mask=mask)
     return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), new_cache

@@ -185,9 +185,9 @@ def kernel_cases(smoke: bool = True) -> List[BenchCase]:
                 ks = jax.random.split(KEY, 3)
                 q = jax.random.normal(ks[0], (2 * b, 1, h, dh),
                                       jnp.float32) * 0.5
-                kc = jax.random.normal(ks[1], (2 * b, c, kv, dh),
+                kc = jax.random.normal(ks[1], (2 * b, kv, dh, c),
                                        jnp.float32) * 0.5
-                vc = jax.random.normal(ks[2], (2 * b, c, kv, dh),
+                vc = jax.random.normal(ks[2], (2 * b, kv, dh, c),
                                        jnp.float32) * 0.5
                 valid = jnp.ones((2 * b, c), jnp.bool_)
 

@@ -49,14 +49,18 @@ class ServeSetup:
 
 
 def _cache_specs(cfg: ModelConfig, dp_axes, *, context_shard: bool):
-    """PartitionSpec per cache leaf (stacked [n_periods, ...] layout)."""
+    """PartitionSpec per cache leaf (stacked [n_periods, ...] layout).
+
+    Attention caches are [n_periods, B, KV, dh, C]: batch-sharded on axis
+    1, or context-sharded on the last, the cache position.
+    """
     ba = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     specs = []
     for kind, _ in tf.period_spec(cfg):
         if kind == "attn":
             if context_shard:
-                s = {"k": P(None, None, ba, None, None),
-                     "v": P(None, None, ba, None, None),
+                s = {"k": P(None, None, None, None, ba),
+                     "v": P(None, None, None, None, ba),
                      "slot_pos": P(None, ba)}
             else:
                 s = {"k": P(None, ba, None, None, None),

@@ -96,8 +96,8 @@ def test_decode_kernel_vs_ref(c, block_k, valid_len):
     b, h, kv, dh = 2, 8, 2, 64
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
-    kc = jax.random.normal(ks[1], (b, c, kv, dh)) * 0.5
-    vc = jax.random.normal(ks[2], (b, c, kv, dh)) * 0.5
+    kc = jax.random.normal(ks[1], (b, kv, dh, c)) * 0.5   # [B,KV,dh,C]
+    vc = jax.random.normal(ks[2], (b, kv, dh, c)) * 0.5
     valid = (jnp.arange(c) < valid_len)[None, :].repeat(b, 0)
     got = pl_decode(q, kc, vc, valid, block_k=block_k, interpret=True)
     want = ref.decode_attention(q, kc, vc, valid, block_k=64)
@@ -157,14 +157,15 @@ def test_decode_stats_merge_equals_full():
     b, c, h, kv, dh = 1, 64, 4, 2, 16
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh))
-    kc = jax.random.normal(ks[1], (b, c, kv, dh))
-    vc = jax.random.normal(ks[2], (b, c, kv, dh))
+    kc = jax.random.normal(ks[1], (b, kv, dh, c))
+    vc = jax.random.normal(ks[2], (b, kv, dh, c))
     valid = jnp.ones((b, c), bool)
     full = ref.decode_attention(q, kc, vc, valid)
-    # two shards of the cache, merged via flash-decoding combine
-    acc1, m1, l1 = ref.decode_attention(q, kc[:, :32], vc[:, :32],
+    # two shards of the cache (split on its last axis, the position),
+    # merged via flash-decoding combine
+    acc1, m1, l1 = ref.decode_attention(q, kc[..., :32], vc[..., :32],
                                         valid[:, :32], return_stats=True)
-    acc2, m2, l2 = ref.decode_attention(q, kc[:, 32:], vc[:, 32:],
+    acc2, m2, l2 = ref.decode_attention(q, kc[..., 32:], vc[..., 32:],
                                         valid[:, 32:], return_stats=True)
     mg = jnp.maximum(m1, m2)
     l = l1 * jnp.exp(m1 - mg) + l2 * jnp.exp(m2 - mg)
@@ -209,8 +210,8 @@ def test_pallas_decode_grads_match_ref():
     b, c, h, kv, dh = 1, 128, 4, 2, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
-    kc = jax.random.normal(ks[1], (b, c, kv, dh)) * 0.5
-    vc = jax.random.normal(ks[2], (b, c, kv, dh)) * 0.5
+    kc = jax.random.normal(ks[1], (b, kv, dh, c)) * 0.5
+    vc = jax.random.normal(ks[2], (b, kv, dh, c)) * 0.5
     valid = (jnp.arange(c) < 100)[None, :].repeat(b, 0)
 
     def loss(fn):
