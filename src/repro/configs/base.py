@@ -28,6 +28,15 @@ class MoEConfig:
     router_jitter: float = 0.0
     # apply MoE FFN every `moe_every` layers (1 = every layer, 2 = alternate)
     moe_every: int = 1
+    # the experts this layer holds: ids [first_held, first_held + n_held)
+    # of the n_experts it routes over (expert parallelism's share; the
+    # default holds them all)
+    n_held: Optional[int] = None
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclass(frozen=True)
@@ -93,12 +102,30 @@ class ModelConfig:
     encoder: Optional[EncoderConfig] = None
     frontend: Optional[FrontendConfig] = None
     rope_theta: float = 10000.0
+    # "rope" | "none" (NoPE: no position embedding in attention)
+    position_embedding: str = "rope"
+    # muP-style multipliers (Granite): the embedding is scaled by
+    # embedding_multiplier, each sub-block's output by residual_multiplier
+    # before it joins the residual, and the logits divided by
+    # logits_scaling; attention_multiplier is the softmax scale (None:
+    # 1/sqrt(head_dim)).  The defaults apply none.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # training extras
     dtype: str = "bfloat16"
     remat: str = "none"  # none | full | dots
     source: str = ""  # provenance tag, e.g. "[arXiv:2401.06066; hf]"
+
+    def __post_init__(self):
+        # a pattern read from JSON is a list: hold it as a tuple, so that
+        # the config stays hashable (it is a static argument of jit)
+        if isinstance(self.layer_pattern, list):
+            object.__setattr__(self, "layer_pattern",
+                               tuple(self.layer_pattern))
 
     # ---- derived -----------------------------------------------------------
     @property

@@ -1,6 +1,9 @@
 """GQA/MQA attention: training/prefill (full-sequence) and cached decode.
 
 Mask modes: causal, causal + sliding window (SWA), full (encoder / cross).
+Self-attention takes rotary embeddings unless the config has none
+(``position_embedding="none"``, NoPE); the softmax scale is the config's
+``attention_multiplier`` where it sets one, else 1/sqrt(head_dim).
 Decode uses either a full KV cache (capacity = max context) or a ring-buffer
 cache of size ``sliding_window`` for SWA archs (true sub-quadratic memory).
 
@@ -92,18 +95,20 @@ def attention(p, x, positions, cfg: ModelConfig, *, causal: bool = True,
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", src, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", src, p["wv"])
-    if context is None:  # rope only for self-attention
+    if context is None and cfg.position_embedding == "rope":
         q = rope_apply(q, positions, cfg.rope_theta)
         k = rope_apply(k, positions, cfg.rope_theta)
+    scale = cfg.attention_multiplier
 
     if (mask is None and context is None and causal
             and x.shape[1] >= FLASH_MIN_SEQ):
         from repro.kernels import ops  # lazy: kernels never import models.attention
-        out = ops.mha(q, k, v, causal=True, window=window)
+        out = ops.mha(q, k, v, causal=True, window=window, scale=scale)
         if prefix_len:
             pre = sdpa(q[:, :prefix_len],
                        _repeat_kv(k[:, :prefix_len], cfg.n_heads),
-                       _repeat_kv(v[:, :prefix_len], cfg.n_heads))
+                       _repeat_kv(v[:, :prefix_len], cfg.n_heads),
+                       scale=scale)
             out = jnp.concatenate([pre.astype(out.dtype), out[:, prefix_len:]],
                                   axis=1)
         return jnp.einsum("bqhd,hdk->bqk", out, p["wo"])
@@ -116,7 +121,7 @@ def attention(p, x, positions, cfg: ModelConfig, *, causal: bool = True,
             qi = jnp.arange(x.shape[1])[:, None]
             ki = jnp.arange(src.shape[1])[None, :]
             mask |= ((qi < prefix_len) & (ki < prefix_len))[None, None]
-    out = sdpa(q, k, v, mask=mask)
+    out = sdpa(q, k, v, mask=mask, scale=scale)
     return jnp.einsum("bqhd,hdk->bqk", out, p["wo"])
 
 
@@ -162,7 +167,7 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
         k = _repeat_kv(cross_kv["k"], cfg.n_heads)
         v = _repeat_kv(cross_kv["v"], cfg.n_heads)
-        out = sdpa(q, k, v)
+        out = sdpa(q, k, v, scale=cfg.attention_multiplier)
         return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), cache
 
     capacity = cache["k"].shape[3]
@@ -170,8 +175,10 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     posv = jnp.reshape(pos, (1,)).astype(jnp.int32)
-    q = rope_apply(q, posv[None], cfg.rope_theta)
-    k_new = rope_apply(k_new, posv[None], cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = rope_apply(q, posv[None], cfg.rope_theta)
+        k_new = rope_apply(k_new, posv[None], cfg.rope_theta)
+    scale = cfg.attention_multiplier
     # [B,1,KV,dh] -> the cache's [B,KV,dh,1]
     k_new, v_new = (jnp.moveaxis(t, 1, 3) for t in (k_new, v_new))
 
@@ -208,7 +215,7 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
         b, _, h, dh = q.shape
         vm = jnp.broadcast_to(valid[None, :], (b, capacity))
         acc, m, l = kref.decode_attention(q, k_cache, v_cache, vm,
-                                          return_stats=True)
+                                          scale=scale, return_stats=True)
         fab = ctx["fabric"]
         m_g = fab.pmax(m)
         scalev = jnp.exp(m - m_g)
@@ -219,12 +226,12 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     elif capacity >= 4096:  # long caches: blocked flash-decode, no repeat_kv
         from repro.kernels import ops
         vm = jnp.broadcast_to(valid[None, :], (q.shape[0], capacity))
-        out = ops.decode_attention(q, k_cache, v_cache, vm)
+        out = ops.decode_attention(q, k_cache, v_cache, vm, scale=scale)
     else:  # sdpa's [B,C,H,dh], made in the one copy the repeat makes
         k = _repeat_kv(jnp.moveaxis(k_cache, 3, 1), cfg.n_heads)
         v = _repeat_kv(jnp.moveaxis(v_cache, 3, 1), cfg.n_heads)
         mask = valid[None, None, None, :]  # [1,1,1,capacity]
-        out = sdpa(q, k, v, mask=mask)
+        out = sdpa(q, k, v, mask=mask, scale=scale)
     return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), new_cache
 
 

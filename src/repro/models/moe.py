@@ -17,6 +17,11 @@ rails never carry it.
 The routed path follows DeepSeek-MoE: softmax router, top-k, gates
 renormalized over the selected experts; shared experts always execute.
 A Switch-style auxiliary load-balance loss is returned for training.
+
+A layer may hold a share of its experts (``MoEConfig.n_held`` from
+``first_held``), as one chip of an expert-parallel group does: it routes
+over all of them and computes only its own experts' part of the result,
+dropping no token for what it does not hold.
 """
 from __future__ import annotations
 
@@ -41,13 +46,15 @@ def moe_init(key, cfg: ModelConfig, dtype):
     moe = cfg.moe
     d = cfg.d_model
     de = moe.d_expert if moe.d_expert is not None else cfg.d_ff
+    e = moe.held
     k_r, k_g, k_u, k_d, k_s = jax.random.split(key, 5)
     p = {
+        # the router scores every expert, held here or not
         "router": dense_init(k_r, (d, moe.n_experts), jnp.float32),
-        # routed experts, stacked on a leading E dim (sharded over `model`)
-        "w_gate": dense_init(k_g, (moe.n_experts, d, de), dtype, in_axis_size=d),
-        "w_up": dense_init(k_u, (moe.n_experts, d, de), dtype, in_axis_size=d),
-        "w_down": dense_init(k_d, (moe.n_experts, de, d), dtype, in_axis_size=de),
+        # the held experts, stacked on a leading E dim (sharded over `model`)
+        "w_gate": dense_init(k_g, (e, d, de), dtype, in_axis_size=d),
+        "w_up": dense_init(k_u, (e, d, de), dtype, in_axis_size=d),
+        "w_down": dense_init(k_d, (e, de, d), dtype, in_axis_size=de),
     }
     if moe.n_shared_experts:
         ks1, ks2, ks3 = jax.random.split(k_s, 3)
@@ -179,9 +186,16 @@ def moe_apply(p, x, cfg: ModelConfig, *, rng: Optional[jax.Array] = None,
         aux = load_balance_loss(probs, idx, moe)
         pos = choice_positions(idx, moe.n_experts)
         fits = pos < capacity
+        if moe.held < moe.n_experts:
+            # a share of the experts: a choice of one held elsewhere is
+            # not dispatched here and adds nothing; the gates stay those
+            # of all k choices
+            idx = idx - moe.first_held
+            mine = (idx >= 0) & (idx < moe.held)
+            idx, fits = jnp.where(mine, idx, 0), fits & mine
 
     with scope("moe_dispatch"):
-        buf = scatter_dispatch(x, idx, pos, fits, moe.n_experts, capacity)
+        buf = scatter_dispatch(x, idx, pos, fits, moe.held, capacity)
         if csp is not None:
             buf = csp(buf, "groups", "experts", None, None)
         if ep_axis is not None:

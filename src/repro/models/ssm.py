@@ -221,10 +221,11 @@ def _ssm_decode(p, x, cache, cfg: ModelConfig):
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, :])
     a = -jnp.exp(p["a_log"])
     dA = jnp.exp(dt * a[None, :])  # [B,H]
-    # state' = dA * state + dt * x ⊗ B
-    new_state = (dA[..., None, None] * cache["state"]
-                 + jnp.einsum("bh,bhp,bhn->bhpn", dt, xin, b_mat))
-    y = jnp.einsum("bhn,bhpn->bhp", c_mat, new_state)
+    with scope("ssm_state"):
+        # state' = dA * state + dt * x ⊗ B, read out as C · state'
+        new_state = (dA[..., None, None] * cache["state"]
+                     + jnp.einsum("bh,bhp,bhn->bhpn", dt, xin, b_mat))
+        y = jnp.einsum("bhn,bhpn->bhp", c_mat, new_state)
     y = y + p["d_skip"][None, :, None] * xin
     y = y.reshape(bsz, d_inner)
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), p["norm_w"],

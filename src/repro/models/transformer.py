@@ -143,6 +143,14 @@ def param_count(params) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _residual(x, h, cfg: ModelConfig):
+    """x + h, the sub-block's output ``h`` scaled by the config's residual
+    multiplier where it sets one."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
 def _apply_sublayer(lp, x, positions, cfg: ModelConfig, spec, *,
                     causal: bool, mask=None, enc_out=None, csp=None,
                     prefix_len: int = 0):
@@ -154,11 +162,11 @@ def _apply_sublayer(lp, x, positions, cfg: ModelConfig, spec, *,
                            prefix_len=prefix_len)
     else:
         h = ssm_mod.ssm_apply(lp["mixer"], h, cfg)
-    x = x + h
+    x = _residual(x, h, cfg)
     if "cross" in lp:
         h = rms_norm(x, lp["norm_x"], cfg.norm_eps)
         h = attn.attention(lp["cross"], h, positions, cfg, context=enc_out)
-        x = x + h
+        x = _residual(x, h, cfg)
     aux = jnp.zeros((), jnp.float32)
     if ffn is not None:
         h = rms_norm(x, lp["norm2"], cfg.norm_eps)
@@ -166,7 +174,7 @@ def _apply_sublayer(lp, x, positions, cfg: ModelConfig, spec, *,
             h, aux = moe_mod.moe_apply(lp["ffn"], h, cfg, csp=csp)
         else:
             h = mlp_apply(lp["ffn"], h, cfg.mlp_act)
-        x = x + h
+        x = _residual(x, h, cfg)
     return x, aux
 
 
@@ -211,7 +219,10 @@ def stack_apply(layers, x, positions, cfg: ModelConfig, *, causal: bool = True,
 
 def _embed_tokens(params, tokens, cfg: ModelConfig):
     with scope("embed"):
-        return params["embed"][tokens]
+        x = params["embed"][tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        return x
 
 
 def _unembed(params, x, cfg: ModelConfig, csp=None):
@@ -221,8 +232,12 @@ def _unembed(params, x, cfg: ModelConfig, csp=None):
             # tied table is stored model-replicated (cheap lookups); shard
             # it on vocab just for the logits contraction — a local slice
             w = csp(w, "vocab", None)
-        return jnp.einsum("...d,vd->...v", x, w)
-    return jnp.einsum("...d,dv->...v", x, params["unembed"])
+        logits = jnp.einsum("...d,vd->...v", x, w)
+    else:
+        logits = jnp.einsum("...d,dv->...v", x, params["unembed"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _prefix_inputs(params, batch, cfg: ModelConfig):
@@ -369,19 +384,19 @@ def decode_step(params, state, token, pos, cfg: ModelConfig, *,
             else:
                 z, nc = ssm_mod.ssm_decode(lp["mixer"], z, per_cache[i], cfg)
             new_cache.append(nc)
-            h = h + z
+            h = _residual(h, z, cfg)
             if "cross" in lp:
                 z = rms_norm(h, lp["norm_x"], cfg.norm_eps)
                 z, _ = attn.decode_attention(lp["cross"], z, pos, None, cfg,
                                              cross_kv=per_cross[i])
-                h = h + z
+                h = _residual(h, z, cfg)
             if ffn is not None:
                 z = rms_norm(h, lp["norm2"], cfg.norm_eps)
                 if ffn == "moe":
                     z, _ = moe_mod.moe_apply(lp["ffn"], z, cfg)
                 else:
                     z = mlp_apply(lp["ffn"], z, cfg.mlp_act)
-                h = h + z
+                h = _residual(h, z, cfg)
         return h, tuple(new_cache)
 
     xs = (params["layers"], state) if cross_state is None else \

@@ -43,9 +43,19 @@ SCOPES = {
 }
 
 
+#: registered scopes that the benchmark's own copy of ``SCOPES``
+#: (``benchmarks/chip/chipbench/scopes.NAMES``) does not list yet; its
+#: readers of ``SCOPES`` charge their ops to the enclosing scope until it
+#: does, and those that read them list them themselves
+NOT_IN_BENCHMARK = {
+    "ssm_state": "models/ssm.ssm_decode: the recurrent state update and "
+                 "its readout",
+}
+
+
 def scope(name: str):
     """``jax.named_scope(name)`` for a registered name."""
-    if name not in SCOPES:
+    if name not in SCOPES and name not in NOT_IN_BENCHMARK:
         raise ValueError(f"unregistered scope {name!r}; add it to "
                          "repro.scopes.SCOPES")
     return jax.named_scope(name)
