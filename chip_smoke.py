@@ -220,8 +220,8 @@ def kernel_phase(*, flash: tuple, decode: tuple, ssd: tuple,
     b, c, h, kv, dh, valid = decode
     mask = jnp.broadcast_to(jnp.arange(c) < valid, (b, c))
     compare("decode_attention", decode,
-            lambda q, k, v, m: da.decode_attention(q, k, v, m,
-                                                   interpret=interpret),
+            lambda q, k, v, m: da.decode_attention(q, k[None], v[None], m,
+                                                   0, interpret=interpret),
             ref.decode_attention, normal((b, 1, h, dh), bf16),
             normal((b, kv, dh, c), bf16), normal((b, kv, dh, c), bf16), mask)
 

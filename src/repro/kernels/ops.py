@@ -9,8 +9,8 @@ Set ``REPRO_KERNELS=pallas_interpret`` to force the Pallas kernels in
 interpret mode (used by the kernel tests on CPU), or ``REPRO_KERNELS=ref``
 to force the oracles even on TPU.  Each dispatch logs the path it took
 (``pallas``, ``interpret`` or ``ref``) on this module's logger, once per
-trace.  Each kernel runs inside its named scope (``repro.scopes``) on
-either path.
+trace; flash-decode's line also names the form of its operands.  Each
+kernel runs inside its named scope (``repro.scopes``) on either path.
 """
 from __future__ import annotations
 
@@ -38,9 +38,11 @@ def _mode() -> str:
     return env
 
 
-def _dispatch(kernel: str) -> str:
+def _dispatch(kernel: str, form: str = "") -> str:
+    """The path ``kernel`` takes, logged with the record's args (kernel,
+    path); ``form`` adds to the message the form of the operands."""
     mode = _mode()
-    log.info("%s: %s", kernel, _PATHS[mode])
+    log.info("%s: %s" + form, kernel, _PATHS[mode])
     return mode
 
 
@@ -58,20 +60,23 @@ def mha(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                        q_offset=q_offset)
 
 
-def decode_attention(q, k_cache, v_cache, valid_mask, *,
+def decode_attention(q, k_cache, v_cache, valid_mask, layer, *,
                      scale: Optional[float] = None):
-    """Flash-decode.  q [B,1,H,dh], caches [B,KV,dh,C], valid [B,C].
+    """Flash-decode over layer ``layer`` of stacked caches.
 
-    The cache position is minor, as the decode cache stores it, so the
-    kernel reads the cache with no relayout.
+    q [B,1,H,dh], caches [L,B,KV,dh,C], valid [B,C], layer an int32
+    scalar.  The kernel reads the layer's tiles in place in the stack, in
+    the stored layout: no slice or relayout of the cache around the call.
     """
-    mode = _dispatch("decode_attention")
+    mode = _dispatch("decode_attention", " (stacked, layer operand)")
     with scope("attn_decode"):
         if mode in ("pallas", "pallas_interpret"):
             from repro.kernels import decode_attention as da
             return da.decode_attention(
-                q, k_cache, v_cache, valid_mask, scale=scale,
+                q, k_cache, v_cache, valid_mask, layer, scale=scale,
                 interpret=(mode == "pallas_interpret"))
+        k_cache, v_cache = (jax.lax.dynamic_index_in_dim(
+            t, layer, 0, keepdims=False) for t in (k_cache, v_cache))
         return ref.decode_attention(q, k_cache, v_cache, valid_mask,
                                     scale=scale)
 

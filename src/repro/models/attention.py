@@ -134,9 +134,9 @@ def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype):
     """k/v [B, KV, dh, C] with the cache position minor; slot_pos [C].
 
     The position is the longest axis, so as the minor one it fills the
-    TPU's 128 lanes with no padding, and it is the layout the stacked
-    cache is stored in and the flash-decode kernel reads: no relayout of
-    the cache in the layer scan or around the kernel.
+    TPU's 128 lanes with no padding.  ``models.transformer`` stacks these
+    leaves over the layers, and the flash-decode kernel reads a layer's
+    tiles in that stack as they lie: no slice or relayout of the cache.
     """
     dh = cfg.resolved_head_dim
     return {
@@ -148,20 +148,23 @@ def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype):
 
 
 def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
-                     window: Optional[int] = None,
+                     layer=None, window: Optional[int] = None,
                      cross_kv: Optional[dict] = None,
                      ctx: Optional[dict] = None):
     """One-token attention. x [B,1,D]; pos scalar int32 (absolute position).
 
+    ``cache`` holds the stacked caches of every layer, ``init_kv_cache``'s
+    leaves with a leading layer axis (k/v [L,B,KV,dh,C], slot_pos [L,C]),
+    and ``layer`` (int32 scalar) picks this layer's.  The new token's K
+    and V are written as [1,B,KV,dh,1] at (layer, slot) in place in the
+    stack, and flash-decode reads the layer inside the stack.
     Full cache: slot = pos.  SWA ring cache: slot = pos % capacity.
-    The cache is ``init_kv_cache``'s [B,KV,dh,C]: the new token's K and V
-    are written as [B,KV,dh,1] at the slot along the last axis.
     ctx = {"fabric": Fabric, "offset": int32} enables context-parallel
     decode: the cache holds only this rail shard's slot range; partial
     flash-decode stats are merged across shards (split-K combine).  The
     merge stats are small per-head scalars — management-class traffic
     (paper Alg 1: CPU frontend network), emitted as pmax/psum.
-    Returns (out [B,1,D], new_cache).
+    Returns (out [B,1,D], new_cache), the whole updated stack.
     """
     if cross_kv is not None:  # cross-attention over cached encoder KV
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -170,7 +173,8 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
         out = sdpa(q, k, v, scale=cfg.attention_multiplier)
         return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), cache
 
-    capacity = cache["k"].shape[3]
+    capacity = cache["k"].shape[4]
+    layer = jnp.asarray(layer, jnp.int32)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -183,39 +187,41 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     k_new, v_new = (jnp.moveaxis(t, 1, 3) for t in (k_new, v_new))
 
     with scope("kv_cache"):
+        owned = None
         if ctx is not None:  # context-parallel: write only if this shard owns pos
             slot_local = (pos - ctx["offset"]).astype(jnp.int32)
             owned = (slot_local >= 0) & (slot_local < capacity)
-            safe = jnp.clip(slot_local, 0, capacity - 1)
-            upd = lambda buf, val: jnp.where(
-                owned, jax.lax.dynamic_update_slice_in_dim(
-                    buf, val.astype(buf.dtype), safe, axis=3), buf)
-            k_cache = upd(cache["k"], k_new)
-            v_cache = upd(cache["v"], v_new)
-            slot_pos = jnp.where(
-                owned, jax.lax.dynamic_update_slice_in_dim(
-                    cache["slot_pos"], posv, safe, axis=0), cache["slot_pos"])
+            slot = jnp.clip(slot_local, 0, capacity - 1)
         else:
             slot = jnp.where(window is None, pos,
                              pos % capacity).astype(jnp.int32)
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), slot, axis=3)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), slot, axis=3)
-            slot_pos = jax.lax.dynamic_update_slice_in_dim(
-                cache["slot_pos"], posv, slot, axis=0)
-        new_cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
 
+        def put(buf, val):  # one slot of layer `layer`, in place
+            idx = (layer,) + (0,) * (buf.ndim - 2) + (slot,)
+            val = val[None].astype(buf.dtype)
+            if owned is not None:
+                val = jnp.where(owned, val,
+                                jax.lax.dynamic_slice(buf, idx, val.shape))
+            return jax.lax.dynamic_update_slice(buf, val, idx)
+
+        new_cache = {"k": put(cache["k"], k_new), "v": put(cache["v"], v_new),
+                     "slot_pos": put(cache["slot_pos"], posv)}
+        slot_pos = jax.lax.dynamic_index_in_dim(new_cache["slot_pos"], layer,
+                                                0, keepdims=False)
         valid = (slot_pos >= 0) & (slot_pos <= pos)
         if window is not None:
             valid &= slot_pos > pos - window
+
+    def this_layer(t):  # off the kernel path: the layer's cache, sliced
+        return jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
 
     if ctx is not None:
         from repro.kernels import ref as kref
         b, _, h, dh = q.shape
         vm = jnp.broadcast_to(valid[None, :], (b, capacity))
-        acc, m, l = kref.decode_attention(q, k_cache, v_cache, vm,
-                                          scale=scale, return_stats=True)
+        acc, m, l = kref.decode_attention(
+            q, this_layer(new_cache["k"]), this_layer(new_cache["v"]), vm,
+            scale=scale, return_stats=True)
         fab = ctx["fabric"]
         m_g = fab.pmax(m)
         scalev = jnp.exp(m - m_g)
@@ -226,10 +232,13 @@ def decode_attention(p, x, pos, cache, cfg: ModelConfig, *,
     elif capacity >= 4096:  # long caches: blocked flash-decode, no repeat_kv
         from repro.kernels import ops
         vm = jnp.broadcast_to(valid[None, :], (q.shape[0], capacity))
-        out = ops.decode_attention(q, k_cache, v_cache, vm, scale=scale)
+        out = ops.decode_attention(q, new_cache["k"], new_cache["v"], vm,
+                                   layer, scale=scale)
     else:  # sdpa's [B,C,H,dh], made in the one copy the repeat makes
-        k = _repeat_kv(jnp.moveaxis(k_cache, 3, 1), cfg.n_heads)
-        v = _repeat_kv(jnp.moveaxis(v_cache, 3, 1), cfg.n_heads)
+        k = _repeat_kv(jnp.moveaxis(this_layer(new_cache["k"]), 3, 1),
+                       cfg.n_heads)
+        v = _repeat_kv(jnp.moveaxis(this_layer(new_cache["v"]), 3, 1),
+                       cfg.n_heads)
         mask = valid[None, None, None, :]  # [1,1,1,capacity]
         out = sdpa(q, k, v, mask=mask, scale=scale)
     return jnp.einsum("bqhd,hdk->bqk", out, p["wo"]), new_cache

@@ -353,37 +353,43 @@ def decode_step(params, state, token, pos, cfg: ModelConfig, *,
                 ctx=None):
     """One decode step.  token [B,1] int32, pos scalar int32.
 
+    The layer scan carries the attention caches' stacks, which each layer
+    updates in place at its own index and flash-decode reads there; the
+    Mamba caches, rewritten whole every step, are its xs and ys.
     ctx: optional context-parallel decode info ({"fabric", "offset"}) for
     caches sharded along the sequence dim over rails (long_500k cells).
     Returns (logits [B,1,V], new_state).
     """
     x = _embed_tokens(params, token, cfg)
     specs = period_spec(cfg)
+    is_attn = [kind == "attn" for kind, _ in specs]
+    kv = tuple(c if a else None for c, a in zip(state, is_attn))
+    rec = tuple(None if a else c for c, a in zip(state, is_attn))
 
     def body(carry, xs):
         with scope("layer"):
             return layer(carry, xs)
 
     def layer(carry, xs):
-        h = carry
+        h, kv = carry
         if cross_state is not None:
-            per_params, per_cache, per_cross = xs
+            per_params, idx, per_rec, per_cross = xs
         else:
-            per_params, per_cache = xs
+            per_params, idx, per_rec = xs
             per_cross = None
         pp = layer_param_fn(per_params) if layer_param_fn else per_params
-        new_cache = []
+        kv, new_rec = list(kv), list(per_rec)
         for i, (kind, ffn) in enumerate(specs):
             lp = pp[i]
             z = rms_norm(h, lp["norm1"], cfg.norm_eps)
             if kind == "attn":
-                z, nc = attn.decode_attention(lp["mixer"], z, pos,
-                                              per_cache[i], cfg,
-                                              window=cfg.sliding_window,
-                                              ctx=ctx)
+                z, kv[i] = attn.decode_attention(lp["mixer"], z, pos, kv[i],
+                                                 cfg, layer=idx,
+                                                 window=cfg.sliding_window,
+                                                 ctx=ctx)
             else:
-                z, nc = ssm_mod.ssm_decode(lp["mixer"], z, per_cache[i], cfg)
-            new_cache.append(nc)
+                z, new_rec[i] = ssm_mod.ssm_decode(lp["mixer"], z,
+                                                   per_rec[i], cfg)
             h = _residual(h, z, cfg)
             if "cross" in lp:
                 z = rms_norm(h, lp["norm_x"], cfg.norm_eps)
@@ -397,12 +403,14 @@ def decode_step(params, state, token, pos, cfg: ModelConfig, *,
                 else:
                     z = mlp_apply(lp["ffn"], z, cfg.mlp_act)
                 h = _residual(h, z, cfg)
-        return h, tuple(new_cache)
+        return (h, tuple(kv)), tuple(new_rec)
 
-    xs = (params["layers"], state) if cross_state is None else \
-        (params["layers"], state, cross_state)
+    xs = (params["layers"], jnp.arange(n_periods(cfg), dtype=jnp.int32), rec)
+    if cross_state is not None:
+        xs += (cross_state,)
     with scope("layer_scan"):
-        x, new_state = jax.lax.scan(body, x, xs)
+        (x, kv), rec = jax.lax.scan(body, (x, kv), xs)
+    new_state = tuple(k if a else r for k, r, a in zip(kv, rec, is_attn))
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _unembed(params, x, cfg)

@@ -192,7 +192,8 @@ def kernel_cases(smoke: bool = True) -> List[BenchCase]:
                 valid = jnp.ones((2 * b, c), jnp.bool_)
 
                 def fn(q, kc, vc, valid):
-                    return ops.decode_attention(q, kc, vc, valid)
+                    return ops.decode_attention(q, kc[None], vc[None],
+                                                valid, 0)
                 return fn, (q, kc, vc, valid)
             cases.append(BenchCase("decode_attention", cls,
                                    {"b": 2 * b, "c": c, "h": h, "kv": kv,

@@ -18,7 +18,8 @@ import jax
 SCOPES = {
     "embed": "models/transformer: token embedding lookup",
     "layer_scan": "models/transformer: the layer scan itself (slicing the "
-                  "stacked params and caches per layer, stacking them out)",
+                  "stacked params and Mamba caches per layer, stacking "
+                  "them out)",
     "layer": "models/transformer: the scan body (norms, residuals, "
              "projections no finer scope claims)",
     "lm_head": "models/transformer: final norm, unembedding and loss",
@@ -28,8 +29,8 @@ SCOPES = {
                "the oracle",
     "attn_flash": "kernels/ops.mha: flash attention, either path",
     "attn_decode": "kernels/ops.decode_attention: flash-decode, either path",
-    "kv_cache": "models/attention.decode_attention: cache write and valid "
-                "mask",
+    "kv_cache": "models/attention.decode_attention: the token's write "
+                "into the carried cache stack, and the valid mask",
     "moe_route": "models/moe: router, top-k, load-balance loss, slot "
                  "positions",
     "moe_dispatch": "models/moe: scatter into the expert buffers",

@@ -99,8 +99,33 @@ def test_decode_kernel_vs_ref(c, block_k, valid_len):
     kc = jax.random.normal(ks[1], (b, kv, dh, c)) * 0.5   # [B,KV,dh,C]
     vc = jax.random.normal(ks[2], (b, kv, dh, c)) * 0.5
     valid = (jnp.arange(c) < valid_len)[None, :].repeat(b, 0)
-    got = pl_decode(q, kc, vc, valid, block_k=block_k, interpret=True)
+    got = pl_decode(q, kc[None], vc[None], valid, 0, block_k=block_k,
+                    interpret=True)
     want = ref.decode_attention(q, kc, vc, valid, block_k=64)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("h,kv,dh,c,layer", [
+    (16, 8, 64, 256, 0),      # granite-3.0's GQA, first layer
+    (16, 8, 64, 256, 1),      # ... a middle one
+    (16, 8, 64, 256, 2),      # ... the last one
+    (8, 2, 128, 256, 1),      # granite-4.0-H: dh 128, 4 query heads a group
+    (16, 8, 64, 300, 2),      # 128 does not divide C: a shorter last block
+    (8, 2, 128, 300, 0),
+], ids=["dh64_first", "dh64_middle", "dh64_last", "dh128", "dh64_ragged",
+        "dh128_ragged"])
+def test_decode_kernel_reads_layer_in_stack(h, kv, dh, c, layer):
+    """Flash-decode on stacked caches [L,B,KV,dh,C] at layer ``layer``
+    equals the oracle on that layer's cache, under a mask with holes."""
+    n_layers, b = 3, 2
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
+    kc = jax.random.normal(ks[1], (n_layers, b, kv, dh, c)) * 0.5
+    vc = jax.random.normal(ks[2], (n_layers, b, kv, dh, c)) * 0.5
+    valid = (jax.random.uniform(ks[3], (b, c)) < 0.5).at[:, 0].set(True)
+    got = pl_decode(q, kc, vc, valid, jnp.int32(layer), block_k=128,
+                    interpret=True)
+    want = ref.decode_attention(q, kc[layer], vc[layer], valid)
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
@@ -206,22 +231,22 @@ def test_pallas_ssd_grads_match_oracle():
 def test_pallas_decode_grads_match_ref():
     # same defect class as ssd: the decode kernel's custom_vjp recomputes
     # through ref.decode_attention; the bool valid_mask gets a float0
-    # cotangent
+    # cotangent; the stacked caches' grads are zero off the read layer
     b, c, h, kv, dh = 1, 128, 4, 2, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
-    kc = jax.random.normal(ks[1], (b, kv, dh, c)) * 0.5
-    vc = jax.random.normal(ks[2], (b, kv, dh, c)) * 0.5
+    kc = jax.random.normal(ks[1], (2, b, kv, dh, c)) * 0.5
+    vc = jax.random.normal(ks[2], (2, b, kv, dh, c)) * 0.5
     valid = (jnp.arange(c) < 100)[None, :].repeat(b, 0)
 
     def loss(fn):
         return lambda q, kc, vc: jnp.sum(jnp.sin(fn(q, kc, vc)))
 
     g1 = jax.grad(loss(lambda q_, k_, v_: pl_decode(
-        q_, k_, v_, valid, block_k=64, interpret=True)),
+        q_, k_, v_, valid, 1, block_k=64, interpret=True)),
         argnums=(0, 1, 2))(q, kc, vc)
     g2 = jax.grad(loss(lambda q_, k_, v_: ref.decode_attention(
-        q_, k_, v_, valid)), argnums=(0, 1, 2))(q, kc, vc)
+        q_, k_[1], v_[1], valid)), argnums=(0, 1, 2))(q, kc, vc)
     for got, want in zip(g1, g2):
         np.testing.assert_allclose(got, want, atol=1e-5)
 

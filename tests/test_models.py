@@ -1,5 +1,6 @@
 """Per-arch smoke tests (reduced configs) + decode/forward consistency."""
 import dataclasses
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,60 @@ def test_decode_matches_forward(arch):
         lg, state = step(state, toks[:, t:t + 1], jnp.int32(t))
         outs.append(lg[:, 0])
     np.testing.assert_allclose(logits_tf, jnp.stack(outs, 1), atol=2e-3)
+
+
+@pytest.mark.parametrize("arch", ["granite_moe_1b_a400m",
+                                  "granite_4_0_h_small"])
+def test_decode_scan_carries_kv_stacks(arch):
+    """The decode layer scan carries the attention caches' stacks; its xs
+    are the layer parameters, the layer index and the Mamba caches."""
+    cfg = get_config(arch, smoke=True)
+    params = jax.eval_shape(lambda: T.init_lm(KEY, cfg))
+    state = jax.eval_shape(lambda: T.init_decode_state(cfg, 2, 64))
+    jaxpr = jax.make_jaxpr(lambda p, st: T.decode_step(
+        p, st, jnp.zeros((2, 1), jnp.int32), jnp.int32(3), cfg))(params, state)
+    scan, = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+    carry = [v.aval.shape for v in scan.invars[nc:nc + nk]]
+    xs = [v.aval.shape for v in scan.invars[nc + nk:]]
+
+    def leaves(attn):
+        return [x.shape for c, (kind, _) in zip(state, T.period_spec(cfg))
+                if (kind == "attn") == attn for x in jax.tree.leaves(c)]
+
+    assert carry[1:] == leaves(True)
+    assert xs == ([x.shape for x in jax.tree.leaves(params["layers"])]
+                  + [(T.n_periods(cfg),)] + leaves(False))
+
+
+def test_decode_kernel_path_matches_ref(monkeypatch, caplog):
+    """Decode steps of the 2-layer granite-3.0 smoke config at capacity
+    4096, where flash-decode reads each layer in the carried stack, give
+    the logits and caches of the oracle path; the kernel's dispatch line
+    names the stacked form."""
+    caplog.set_level(logging.INFO, logger="repro.kernels.ops")
+    cfg = get_config("granite_moe_1b_a400m", smoke=True).replace(
+        dtype="float32")
+    params = T.init_lm(KEY, cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 3), 0,
+                              cfg.vocab_size, jnp.int32)
+
+    def run(mode):
+        monkeypatch.setenv("REPRO_KERNELS", mode)
+        step = jax.jit(lambda st, t, p: T.decode_step(params, st, t, p, cfg))
+        state, outs = T.init_decode_state(cfg, 2, capacity=4096), []
+        for t in range(toks.shape[1]):
+            lg, state = step(state, toks[:, t:t + 1], jnp.int32(t))
+            outs.append(lg[:, 0])
+        return jnp.stack(outs, 1), state
+
+    got, got_state = run("pallas_interpret")
+    assert ("decode_attention: interpret (stacked, layer operand)"
+            in caplog.messages), caplog.messages
+    want, want_state = run("ref")
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for g, w in zip(jax.tree.leaves(got_state), jax.tree.leaves(want_state)):
+        np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
 
 
 def test_jamba_decode_matches_with_big_capacity_factor():
