@@ -24,7 +24,7 @@ one deterministic diurnal+burst trace and writes
 ``BENCH_opus_serve.json`` — req/s-per-watt and p99 TTFT, OCS vs packet;
 ``--planner`` evaluates the capacity-planner fabric grid (DESIGN.md
 §12: backend x radix x ports x policy Pareto frontier) plus the two
-vectorized-engine headline points (a 100k-GPU single job and a 256-job
+fast-forward headline points (a 100k-GPU single job and a 256-job
 week-long cluster trace, each in seconds) and writes
 ``BENCH_opus_planner.json``; ``--scheduler-ab`` runs the DESIGN.md §13
 A/B — phase_boundary vs per_collective circuit scheduling on EP-heavy
@@ -433,7 +433,7 @@ def planner_report(out_path: str = "BENCH_opus_planner.json") -> dict:
     """Capacity-planner grid (DESIGN.md §12): every FabricSpec cell
     priced three ways (train overhead, cluster queueing, serving p99)
     through the real control plane, reduced to a Pareto frontier, plus
-    the two scale points the vectorized engine makes affordable —
+    the two scale points the event engine's fast-forward makes affordable —
     100,000 GPUs in one job, and 256 jobs across a simulated week —
     each in seconds of wall clock."""
     from repro.sim.planner import OBJECTIVES, plan
@@ -484,7 +484,7 @@ def ops_report(out_path: str = "BENCH_opus_ops.json") -> dict:
     from repro.sim.cluster import ClusterJobSpec, ClusterParams
     from repro.sim.ops import (DefragPolicy, DrainWindow, ScenarioEngine,
                                diff_twin, run_scenario)
-    from repro.sim.opus_sim import SimParams, VectorEngine
+    from repro.sim.opus_sim import EventEngine, SimParams
     from repro.sim.workload import build
 
     t_all = time.perf_counter()
@@ -499,7 +499,7 @@ def ops_report(out_path: str = "BENCH_opus_ops.json") -> dict:
 
     # -- flap inside the retry budget: survives, no demotion
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=0.4),))
-    eng = VectorEngine(wl, sp, ocs_fail=fm, iterations=8)
+    eng = EventEngine(wl, sp, ocs_fail=fm, iterations=8)
     eng.run()
     survival = dict(eng.plane.fault_stats())
     print(f"  flap 0.4s: {survival['n_retries']} retries, "
@@ -508,7 +508,7 @@ def ops_report(out_path: str = "BENCH_opus_ops.json") -> dict:
 
     # -- flap past the budget: demote -> repair -> fast-forward re-arms
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=5.0),))
-    eng = VectorEngine(wl, sp, ocs_fail=fm, iterations=30)
+    eng = EventEngine(wl, sp, ocs_fail=fm, iterations=30)
     eng.run()
     recovery = dict(eng.plane.fault_stats())
     recovery["fastforwarded_iterations"] = eng.fastforwarded_iterations

@@ -12,7 +12,7 @@ overhead, cluster queueing, and serving p99 TTFT.
     PYTHONPATH=src python examples/plan_fabric.py --ports 64 128 \
         --gpu gb200 --all-cells
 
-``--headline`` additionally runs the two scale points the vectorized
+``--headline`` additionally runs the two scale points the fast-forwarding
 event engine (DESIGN.md §12) makes affordable on a laptop: one
 100,000-GPU training job, and 256 jobs arriving across a simulated
 week — each in seconds of wall clock.

@@ -9,7 +9,7 @@ migrate, and a fleet you can diff (DESIGN.md §14).
 ``flap``    one tenant rides transient link flaps: a short flap is
             absorbed by the retry/backoff budget; a long one demotes to
             the giant ring, then REPAIRS — the requested topology is
-            restored, the replay cache re-promotes, and the vectorized
+            restored, the replay cache re-promotes, and the event
             engine's fast-forward re-arms.
 ``drain``   a scheduled maintenance window reserves half the port space;
             resident tenants checkpoint-restart onto surviving ports
@@ -31,7 +31,7 @@ from repro.core.phases import JobConfig
 from repro.sim.cluster import ClusterJobSpec, ClusterParams
 from repro.sim.ops import (DefragPolicy, DrainWindow, ScenarioEngine,
                            diff_twin, run_scenario, write_twin_jsonl)
-from repro.sim.opus_sim import SimParams, VectorEngine
+from repro.sim.opus_sim import EventEngine, SimParams
 from repro.sim.workload import build
 
 CFG = get_config("llama3_8b")
@@ -46,7 +46,7 @@ def scenario_flap():
     params = SimParams(mode="opus_prov", ocs_latency=0.01)
     # short flap: one retry (+1s timeout) outlives the 0.4s outage
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=0.4),))
-    eng = VectorEngine(wl, params, ocs_fail=fm, iterations=8)
+    eng = EventEngine(wl, params, ocs_fail=fm, iterations=8)
     eng.run()
     fs = eng.plane.fault_stats()
     print(f"flap (0.4s, inside retry budget): {fs['n_retries']} retries, "
@@ -55,7 +55,7 @@ def scenario_flap():
     # long flap: budget exhausted -> giant ring; repair restores the
     # requested topology and fast-forward re-arms past the flap horizon
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=5.0),))
-    eng = VectorEngine(wl, params, ocs_fail=fm, iterations=30)
+    eng = EventEngine(wl, params, ocs_fail=fm, iterations=30)
     eng.run()
     fs = eng.plane.fault_stats()
     print(f"flap (5s, budget exhausted): {fs['n_demotions']} demotion, "

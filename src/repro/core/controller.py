@@ -263,7 +263,7 @@ class Controller:
         (``RailOrchestrator.repair``) to its pending target — a digit-diff
         ``apply`` would under-program ways the suppressed barriers never
         named.  After this the replay cache re-promotes (``replay_ready``
-        keys off the fallback flag) and the vector engine's fast-forward
+        keys off the fallback flag) and the event engine's fast-forward
         re-arms."""
         assert self.fallback_giant_ring, "recover() outside fallback"
         ack = now

@@ -128,7 +128,7 @@ class FaultModel:
     @property
     def horizon(self) -> float:
         """Time after which no flap can ever fire again — past this the
-        vector engine may capture a steady iteration and fast-forward
+        event engine may capture a steady iteration and fast-forward
         (nothing left to perturb the cycle)."""
         return max((f.end for f in self.flaps), default=0.0)
 

@@ -413,8 +413,7 @@ def phase_index_of(ops: Iterable[CommOp],
     Array-backed (op uids are dense from 0): an int64 numpy vector filled
     with one slice-assignment per phase and shared by every phase-aware
     driver — both simulator engines index it instead of each rebuilding a
-    per-uid dict, and the vectorized engine uses it directly as the class
-    key for its batched per-phase walks.
+    per-uid dict (the event engine copies it into its per-op table).
     """
     ops = list(ops)
     if table is None:

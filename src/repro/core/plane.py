@@ -442,12 +442,12 @@ class ControlPlane:
         for o in self.orchestrators:
             o.deregister_job(self.job_id, now)
 
-    # -- steady-state bulk advance (vectorized engine, DESIGN.md §12) -------
+    # -- steady-state bulk advance (engine fast-forward, DESIGN.md §12) -----
     @property
     def replay_ready(self) -> bool:
         """True at an iteration boundary where the promoted schedule cache
         will replay the NEXT iteration verbatim — the precondition for the
-        vectorized engine's fast-forward (a full steady iteration's effect
+        event engine's fast-forward (a full steady iteration's effect
         is then exactly reproducible without walking it)."""
         return (self._cache_enabled and self._sched is not None
                 and self._cursor == 0

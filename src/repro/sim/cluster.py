@@ -44,7 +44,7 @@ from repro.core.fabric import FabricSpec, OCSArray
 from repro.core.orchestrator import PortAllocator, RailOrchestrator
 from repro.core.plane import ControlPlane
 from repro.sim.opus_sim import (SHIM_MODE, EventEngine, SimParams, SimResult,
-                                VectorEngine, simulate)
+                                simulate)
 from repro.sim.workload import GPUS, build, build_serving
 
 
@@ -115,7 +115,7 @@ class ClusterJobSpec:
     batch_slots: int = 16         # resident slots (serve_decode only)
     # minimum SIMULATED runtime: the tenant departs at the first
     # iteration boundary at or past admitted + runtime_s (week-long
-    # traces).  The vectorized engine fast-forwards the steady cycles, so
+    # traces).  The event engine fast-forwards the steady cycles, so
     # a week-long tenant costs the same wall time as a two-iteration one
     # (DESIGN.md §12).  None (default) keeps the fixed iteration count —
     # byte-identical to the pre-runtime cluster.
@@ -176,12 +176,6 @@ class JobRecord:
 
 class ClusterSim:
     """N concurrent jobs through shared per-rail OCS port space."""
-
-    #: engine class each tenant runs on — the vectorized array-backed
-    #: core by default (bit-identical on fixed-iteration tenants; fast-
-    #: forwards ``runtime_s`` tenants).  Parity tests override this with
-    #: ``EventEngine`` to prove the cluster numbers are engine-invariant.
-    ENGINE_CLS = VectorEngine
 
     def __init__(self, params: ClusterParams, *,
                  ops: Optional[object] = None, twin: bool = False):
@@ -360,12 +354,12 @@ class ClusterSim:
                                calibration=self.params.calibration)
         kw = {}
         if rec.spec.runtime_s is not None and rec.resume_iterations is None:
-            # runtime-sized tenants need the vectorized engine's fast-
-            # forward; the fixed-iteration path works on any engine class.
-            # A checkpoint-restarted tenant resumes by ITERATION remainder
-            # (the scenario engine sized it), never by re-running runtime.
+            # runtime-sized tenants run until the engine's fast-forward
+            # reaches runtime_s.  A checkpoint-restarted tenant resumes by
+            # ITERATION remainder (the scenario engine sized it), never by
+            # re-running runtime.
             kw["min_runtime_s"] = rec.spec.runtime_s
-        return self.ENGINE_CLS(
+        return EventEngine(
             wl, SimParams(mode=rec.spec.mode,
                           ocs_latency=self.params.ocs_latency,
                           nic_linkup=self.params.nic_linkup,

@@ -25,30 +25,25 @@ SwitchBackend (DESIGN.md §10; override via SimParams.backend/fabric):
             control residue.
 
 Engines
-  event     DEFAULT: the vectorized array-backed engine (DESIGN.md §12).
-            Live iterations replay the timed workload through the REAL
-            control plane exactly like the collapsed engine below — the
-            same floating-point expressions, read from precomputed per-op
-            duration/phase tables — and once the plane's replay cache
-            holds a complete steady cycle, every REMAINING iteration is
-            applied as one vectorized walk: clock += k * step,
-            counters += k * per-iteration-delta (numpy snapshot math in
-            ``ControlPlane.bulk_advance``).  Runs that measure the paper's
-            two-iteration convention never fast-forward, so every
-            committed BENCH counter is byte-identical to the collapsed
-            engine; longer runs (``iterations > 2``, ``min_runtime_s``)
-            are where the array path pays off.
-  event_collapsed  The collapsed per-op engine (PR 2): one representative
-            Shim per pipeline way, weighted barriers, one batched plane
-            call per op, every op walked live.  Kept as the vectorized
-            engine's ground truth (three-way parity tests).
-  event_full  The same event engine on an UNCOLLAPSED plane (one Shim and
-            one weighted-1 barrier write per rank).  O(ops x ranks)
-            Python dispatch; kept as the ground truth the collapsed plane
-            is tested bit-identical against (tests/test_plane_collapse).
+  event     DEFAULT: ``EventEngine`` on the collapsed control plane (one
+            representative Shim per pipeline way, weighted barriers, one
+            batched plane call per op; DESIGN.md §8, §12).  It replays the
+            timed workload through the REAL control plane, reading per-op
+            durations and phases from precomputed tables, and once the
+            plane's replay cache holds a complete steady cycle it applies
+            every REMAINING iteration as one vectorized walk: clock +=
+            k * step, counters += k * per-iteration-delta
+            (``ControlPlane.bulk_advance``).  The default iteration counts
+            never fast-forward; longer runs (``iterations > 2``,
+            ``min_runtime_s``) are where the array path pays off.  A plane
+            with a fault injector walks every iteration live.
+  event_full  The same engine on an UNCOLLAPSED plane (one Shim and one
+            weighted-1 barrier write per rank).  O(ops x ranks) Python
+            dispatch; kept as the ground truth the collapsed plane is
+            tested bit-identical against (tests/test_plane_collapse).
   analytic  The original closed-form model (digit-diff reconfig counting,
             inlined exposure formulas), kept as a cross-check; the parity
-            contract with the event engines is tested in
+            contract with the event engine is tested in
             tests/test_plane.py and documented in DESIGN.md §4.
 
 Reconfiguration counting matches core.phases.count_reconfigs (digit-diff
@@ -190,13 +185,12 @@ def simulate(wl: TimedWorkload, params: SimParams, *,
     """Simulate one steady-state iteration.
 
     ``engine`` selects the implementation: ``"event"`` (default, EVERY
-    mode) is the vectorized array-backed engine on the collapsed control
-    plane (DESIGN.md §12), ``"event_collapsed"`` the per-op collapsed
-    engine it is tested bit-identical against, ``"event_full"`` the same
-    plane uncollapsed (per-rank, O(ranks) dispatch — the parity ground
-    truth), ``"analytic"`` the closed-form cross-check.  ``ocs_fail`` is
-    the event engines' fault injector (``attempt -> bool``; persistent
-    True triggers the §4.2 giant-ring fallback).
+    mode) is :class:`EventEngine` on the collapsed control plane
+    (DESIGN.md §12), ``"event_full"`` the same engine on the uncollapsed
+    plane (per-rank, O(ranks) dispatch — the parity ground truth),
+    ``"analytic"`` the closed-form cross-check.  ``ocs_fail`` is the
+    event engine's fault injector (``attempt -> bool``; persistent True
+    triggers the §4.2 giant-ring fallback).
     """
     if params.static_fabric:
         assert ocs_fail is None, \
@@ -211,12 +205,10 @@ def simulate(wl: TimedWorkload, params: SimParams, *,
             "the closed-form model only covers phase-boundary " \
             "scheduling; per-collective rounds need an event engine"
         return _simulate_analytic(wl, params)
-    if eng == "event":
-        return VectorEngine(wl, params, ocs_fail=ocs_fail).run()
-    if eng not in ("event_collapsed", "event_full"):
+    if eng not in ("event", "event_full"):
         raise ValueError(f"unknown engine {eng!r}")
-    return _simulate_event(wl, params, ocs_fail,
-                           collapse=(eng == "event_collapsed"))
+    return EventEngine(wl, params, ocs_fail=ocs_fail,
+                       collapse=(eng == "event")).run()
 
 
 # ---------------------------------------------------------------------------
@@ -240,31 +232,20 @@ def build_plane(job: ph.JobConfig, params: SimParams,
                         collapse=collapse)
 
 
-def _phase_info(wl: TimedWorkload, scheduler: str = "phase_boundary",
-                circuit: bool = False):
-    """(phase table, uid -> phase-index vector) for a workload — now keyed
-    by CONFIG IDENTITY instead of re-hashing the op tuple: ``workload.
-    build``/``build_serving`` are lru-cached per (job, gpu), so every
-    tenant of a shared shape holds the same TimedWorkload instance and
-    this delegates to its per-instance cache (one phase table per config
-    across a whole ClusterSim, zero tuple hashing)."""
-    return wl.phase_info(scheduler, circuit=circuit)
-
-
 def _op_meta(wl: TimedWorkload, params: SimParams,
              scheduler: str = "phase_boundary",
              circuit: bool = False) -> List[tuple]:
-    """Precomputed per-op table for the vectorized engine: one entry per
+    """Precomputed per-op table for the event engine: one entry per
     SCHEDULED op (DESIGN.md §13), ``(kind, op, compute_before,
     dur_healthy, dur_fallback, phase_index)`` with kind 0=mgmt,
     1=scale_up, 2=scale_out.
 
-    Durations are evaluated with EXACTLY the expressions the per-op
-    collapsed engine uses (same operand order, same literals), so reading
-    them back preserves bit-identical floats.  Cached per (workload
-    instance, mode, scheduler): the tables depend only on the job/gpu
-    shape, the mode's bandwidth split and the scheduled stream, so a
-    256-job cluster sharing one config builds them once."""
+    Durations use the closed-form model's expressions (same operand
+    order, same literals), so a zero-start static run is float-identical
+    to it.  Cached per (workload instance, mode, scheduler): the tables
+    depend only on the job/gpu shape, the mode's bandwidth split and the
+    scheduled stream, so a 256-job cluster sharing one config builds
+    them once."""
     cache = wl.__dict__.setdefault("_op_meta", {})
     key = (params.mode, scheduler, circuit)
     meta = cache.get(key)
@@ -294,46 +275,61 @@ def _op_meta(wl: TimedWorkload, params: SimParams,
     return meta
 
 
-def _mgmt_op(op, t: float, t0: float, timeline: List[TimedOp]) -> float:
-    start = t
-    dur = MGMT_LAT + op.bytes_per_gpu * 8 / (MGMT_GBPS * 1e9)
-    timeline.append(TimedOp(op, start - t0, start + dur - t0))
-    return start + dur
-
-
 class EventEngine:
-    """One job's event-engine run, resumable op by op.
+    """One job's event-engine run, resumable op by op (DESIGN.md §12).
 
-    The former ``_simulate_event`` loop restructured as a generator so the
-    cluster scheduler (``repro.sim.cluster``) can interleave many jobs on
-    one merged timeline: each ``next()`` on :meth:`events` processes
-    exactly one workload op and yields the engine clock.  ``simulate()``
-    drains the generator in one go, so a single-job cluster executes the
-    IDENTICAL floating-point sequence as the single-job engine (asserted
-    bit-exact in tests/test_cluster.py).
+    Each ``next()`` on :meth:`events` processes exactly one workload op
+    and yields the engine clock, so the cluster scheduler
+    (``repro.sim.cluster``) can interleave many jobs on one merged
+    timeline; ``simulate()`` drains the generator in one go, so a
+    single-job cluster executes the IDENTICAL floating-point sequence as
+    the single-job engine (asserted bit-exact in tests/test_cluster.py).
+
+    Live iterations read precomputed per-op (duration, phase) tables
+    (:func:`_op_meta`).  Once one full steady iteration has replayed from
+    the plane's schedule cache, its effect is captured as (clock delta,
+    numpy counter-delta snapshot) and every remaining iteration is
+    applied as ONE vectorized walk: ``t += k * step`` and
+    ``ControlPlane.bulk_advance(k)`` — no per-op ``next()``, no plane
+    calls.  Integer telemetry of a steady iteration is exactly cyclic, so
+    the fast-forwarded counters equal a live walk's; the measured-
+    iteration floats are the captured iteration's (iteration-relative,
+    hence reusable verbatim).  The default iteration counts (1 on static
+    fabrics, 2 otherwise) never fast-forward.
+
+    A plane with a fault injector walks every op live — except past the
+    horizon of a recovering ``FaultModel`` (DESIGN.md §14) — so an
+    injector that never fires is the live-walk reference the tests hold
+    the fast-forward to.
 
     ``plane`` injects a pre-built ControlPlane (cluster mode: shared-rail
     planes with PortAllocator grants); by default the engine builds its
-    own private-rail plane, exactly as before.  ``start`` offsets the
-    engine clock (a cluster job begins at its admission time); per-
-    iteration quantities are all relative to the iteration start, so
-    SimResult is offset-invariant in every field except the timeline's
-    absolute clock base.
+    own private-rail plane.  ``start`` offsets the engine clock (a
+    cluster job begins at its admission time); per-iteration quantities
+    are all relative to the iteration start, so SimResult is offset-
+    invariant in every field except the timeline's absolute clock base.
+    ``min_runtime_s`` sizes the run by SIMULATED time instead of a fixed
+    iteration count: warmup + one captured iteration live, then however
+    many fast-forwarded cycles reach the target.
     """
 
     def __init__(self, wl: TimedWorkload, params: SimParams, *,
                  ocs_fail: Optional[Callable[[int], bool]] = None,
                  collapse: bool = True,
                  plane: Optional[ControlPlane] = None,
-                 start: float = 0.0, iterations: Optional[int] = None):
+                 start: float = 0.0, iterations: Optional[int] = None,
+                 min_runtime_s: Optional[float] = None):
         if iterations is None:
             # static fabrics have no topology state to warm into a cyclic
             # steady state — one iteration IS the steady state (and starts
             # at the engine clock base, so a zero-start run is float-
-            # identical to the closed-form model)
-            iterations = 1 if params.static_fabric else 2
+            # identical to the closed-form model); runtime-sized runs need
+            # warmup + one captured steady iteration on every fabric
+            iterations = 1 if (params.static_fabric
+                               and min_runtime_s is None) else 2
         assert iterations >= (1 if params.static_fabric else 2), \
             "warmup + at least one measured iteration"
+        assert min_runtime_s is None or min_runtime_s > 0.0, min_runtime_s
         self.wl = wl
         self.params = params
         # the §13 scheduler axis: the stream the plane drives is the
@@ -341,8 +337,7 @@ class EventEngine:
         # default scheduler on this path returns wl.ops ITSELF unless an
         # all-to-all needs the circuit execution tax).  With an injected
         # plane (cluster/fleet mode) the fabric is the plane's — the
-        # tenant's mode is never re-validated against it, exactly as
-        # before the scheduler axis existed.
+        # tenant's mode is never re-validated against it.
         if plane is not None:
             self.circuit = plane.spec.circuit_switched
             self.scheduler = params.scheduler \
@@ -357,6 +352,7 @@ class EventEngine:
         self.plane.profile(self.ops, table=wl.shim_table(
             self.scheduler, circuit=self.circuit))
         self.iterations = iterations
+        self.min_runtime_s = min_runtime_s
         self.t = start
         self.result: Optional[SimResult] = None
         self._started = False
@@ -364,192 +360,12 @@ class EventEngine:
         # mid-run by a maintenance drain; the scenario engine reads this
         # to size the checkpoint-restart remainder — DESIGN.md §14)
         self.iterations_done = 0
-
-    def events(self):
-        """Generator: one workload op per step, yielding the clock after
-        each; ``self.result`` is populated when it is exhausted."""
-        assert not self._started, "events() is single-shot per engine"
-        self._started = True
-        wl, params, plane = self.wl, self.params, self.plane
-        job, gpu = wl.job, wl.gpu
-        ctrl_sync, ctrl_async = params.resolved(job.n_gpus)
-        _, phase_of = _phase_info(wl, self.scheduler, self.circuit)
-        dilation = _giant_ring_dilation(job)  # fault fallback bw factors
-        # oneshot: the patched-once fabric splits NIC bandwidth statically
-        # across the scale-out dims (same sqrt-allocation, and the same
-        # floating-point expression, as the closed-form model)
-        shares = _static_split(job) if params.mode == "oneshot" else {}
-
-        t = self.t
-        pending_ready: Optional[float] = None   # provisioned reconfig's ACK
-        step_time = 0.0
-        timeline: List[TimedOp] = []
-        n_reconfigs = n_writes = 0
-        exposed_r = exposed_c = 0.0
-        tel0: Dict[str, object] = {}
-        for iteration in range(self.iterations):  # warmup + measured
-            # degrade-and-recover (DESIGN.md §14): a demoted job whose
-            # rails are clear of outage windows restores the requested
-            # topology at the iteration boundary.  Legacy injectors leave
-            # plane.fault_model None, so this is a no-op exactly as today.
-            if plane.fallback_giant_ring and plane.can_recover(t):
-                t = plane.recover(t)
-            plane.start_iteration()
-            if iteration == self.iterations - 1:
-                tel0 = plane.telemetry()  # measured-iteration deltas base
-            t0 = t
-            timeline = []
-            n_reconfigs = n_writes = 0
-            exposed_r = exposed_c = 0.0
-            prev_phase = -1
-            for op in self.ops:
-                t += op.compute_before
-                if op.scale == "mgmt":
-                    t = _mgmt_op(op, t, t0, timeline)
-                    self.t = t
-                    yield t
-                    continue
-                if op.scale == "scale_up":
-                    self.t = t
-                    yield t
-                    continue  # TP never touches the rails
-
-                pi = phase_of[op.uid]
-                new_phase = pi != prev_phase
-                if new_phase and pending_ready is not None:
-                    # §4.2: a provisioned reconfiguration is exposed only
-                    # past the window; split residue between control and
-                    # OCS time
-                    exp = max(0.0, pending_ready - t)
-                    exposed_c += min(exp, ctrl_async)
-                    exposed_r += max(0.0, exp - ctrl_async)
-                    t = max(t, pending_ready)
-                    pending_ready = None
-
-                # Algorithm 1 on every rank (one batched plane call; the
-                # barrier completes at the last class write)
-                ev = plane.pre_comm_all(op, now=t)
-                write = ev.write if (ev.write is not None
-                                     and ev.write.complete) else None
-                if write is not None:
-                    n_writes += 1
-                    if write.reconfigured:
-                        # on-demand: barrier + OCS latency fully exposed
-                        n_reconfigs += 1
-                        exposed_c += ctrl_sync
-                        exposed_r += write.ack_time - t
-                        t = write.ack_time + ctrl_sync
-                    else:
-                        # lock-free write (suppressed / per-op PP)
-                        exposed_c += PP_OP_CTRL
-                        t += PP_OP_CTRL
-
-                # the collective itself, at the mode's bandwidth
-                bw = gpu.scale_out_gbps
-                if shares:
-                    bw = gpu.scale_out_gbps * max(shares.get(op.dim, 1.0),
-                                                  1e-3)
-                if plane.fallback_giant_ring:
-                    # reduced-bandwidth static ring: a k-rank subgroup
-                    # ring embedded in the N-port cycle dilutes every link
-                    # by the forwarding hops, ~k/N effective bandwidth
-                    # (DESIGN.md §5)
-                    bw *= dilation.get(op.dim, 1.0)
-                start = t
-                t = start + wl.comm_time(op, bandwidth_gbps=bw)
-                timeline.append(TimedOp(op, start - t0, t - t0))
-                prev_phase = pi
-
-                # Algorithm 2 on every rank (provisioning writes ride
-                # here, dispatched after the async control residue)
-                ev = plane.post_comm_all(op, now=t + ctrl_async)
-                write = ev.write if (ev.write is not None
-                                     and ev.write.complete) else None
-                if write is not None:
-                    n_writes += 1
-                    if write.reconfigured:
-                        n_reconfigs += 1
-                        pending_ready = write.ack_time
-                    else:
-                        exposed_c += PP_OP_CTRL
-                        t += PP_OP_CTRL
-                self.t = t
-                yield t
-            step_time = t - t0
-            self.iterations_done = iteration + 1
-        # plane telemetry counts the WHOLE plane lifetime (job
-        # registration + warmup + measured iteration); the "measured"
-        # sub-dict is the steady-state per-iteration delta
-        tel = plane.telemetry()
-        tel["measured"] = {k: tel[k] - tel0[k] for k in tel
-                           if isinstance(tel[k], int)
-                           and not isinstance(tel[k], bool)}
-        tel["calls"] = plane.call_stats()   # perf tracking (BENCH json)
-        self.result = SimResult(
-            step_time, n_reconfigs, n_writes, exposed_r, exposed_c,
-            timeline, engine="event" if plane.collapse else "event_full",
-            telemetry=tel)
-
-    def run(self) -> SimResult:
-        for _ in self.events():
-            pass
-        assert self.result is not None
-        return self.result
-
-
-def _simulate_event(wl: TimedWorkload, params: SimParams,
-                    ocs_fail: Optional[Callable[[int], bool]],
-                    collapse: bool = True) -> SimResult:
-    return EventEngine(wl, params, ocs_fail=ocs_fail,
-                       collapse=collapse).run()
-
-
-class VectorEngine(EventEngine):
-    """Array-backed engine (DESIGN.md §12): the default behind
-    ``engine="event"``.
-
-    Live iterations read precomputed per-op (duration, phase) tables
-    (:func:`_op_meta`) instead of re-deriving bandwidth splits per op, but
-    advance the clock with the SAME floating-point expressions in the same
-    order as :class:`EventEngine` — a two-iteration run is bit-identical
-    to the collapsed engine in every float and every counter (the BENCH
-    byte-identity contract, tests/test_vector_engine.py).
-
-    Once one full steady iteration has replayed from the plane's schedule
-    cache, its effect is captured as (clock delta, numpy counter-delta
-    snapshot) and every remaining iteration is applied as ONE vectorized
-    walk: ``t += k * step`` and ``ControlPlane.bulk_advance(k)`` — no
-    per-op ``next()``, no plane calls.  Integer telemetry of a steady
-    iteration is exactly cyclic, so the fast-forwarded counters equal a
-    live walk's; the measured-iteration floats are the captured
-    iteration's (iteration-relative, hence reusable verbatim).
-
-    ``min_runtime_s`` sizes the run by SIMULATED time instead of a fixed
-    iteration count: the engine walks warmup + one captured iteration
-    live, then fast-forwards however many cycles reach the target — a
-    week-long tenant costs the same wall time as a two-iteration one.
-    Fault injection (``ocs_fail``/giant-ring fallback) disables
-    fast-forwarding: faulted runs walk every op live, identical to the
-    collapsed engine.
-    """
-
-    def __init__(self, wl: TimedWorkload, params: SimParams, *,
-                 ocs_fail: Optional[Callable[[int], bool]] = None,
-                 collapse: bool = True,
-                 plane: Optional[ControlPlane] = None,
-                 start: float = 0.0, iterations: Optional[int] = None,
-                 min_runtime_s: Optional[float] = None):
-        if min_runtime_s is not None and iterations is None:
-            # runtime-sized runs need warmup + one captured steady
-            # iteration even on static fabrics (whose default is 1)
-            iterations = 2
-        super().__init__(wl, params, ocs_fail=ocs_fail, collapse=collapse,
-                         plane=plane, start=start, iterations=iterations)
-        assert min_runtime_s is None or min_runtime_s > 0.0, min_runtime_s
-        self.min_runtime_s = min_runtime_s
         self.fastforwarded_iterations = 0
 
     def events(self):
+        """Generator: one workload op per step (or one fast-forward of
+        many iterations), yielding the clock after each; ``self.result``
+        is populated when it is exhausted."""
         assert not self._started, "events() is single-shot per engine"
         self._started = True
         wl, params, plane = self.wl, self.params, self.plane
@@ -560,14 +376,14 @@ class VectorEngine(EventEngine):
         # EXCEPT a recovering FaultModel, whose flap schedule has a known
         # horizon: past it nothing can perturb the cycle, so after one
         # fully-steady live iteration fast-forward RE-ARMS (DESIGN.md
-        # §14).  Legacy callables keep ff permanently off, as before.
+        # §14).  Legacy callables keep ff permanently off.
         faultable = plane.ocs_fail is not None
         ff_fault = plane.fault_model
         target = None if self.min_runtime_s is None \
             else self.t + self.min_runtime_s
 
         t = self.t
-        pending_ready: Optional[float] = None
+        pending_ready: Optional[float] = None   # provisioned reconfig's ACK
         step_time = 0.0
         timeline: List[TimedOp] = []
         n_reconfigs = n_writes = 0
@@ -601,14 +417,18 @@ class VectorEngine(EventEngine):
                     self.t = t
                     yield t
                 continue
-            # ---- live iteration (bit-identical to EventEngine) ----
+            # ---- live iteration ----
+            # degrade-and-recover (DESIGN.md §14): a demoted job whose
+            # rails are clear of outage windows restores the requested
+            # topology at the iteration boundary.  Legacy injectors leave
+            # plane.fault_model None, so this never fires for them.
             recovered = False
             if plane.fallback_giant_ring and plane.can_recover(t):
                 t = plane.recover(t)
                 recovered = True
             plane.start_iteration()
             if not captured:
-                tel0 = plane.telemetry()
+                tel0 = plane.telemetry()  # measured-iteration deltas base
             will_capture = ff_ok and not captured and plane.replay_ready
             if will_capture:
                 snap0 = plane.counter_snapshot()
@@ -625,34 +445,45 @@ class VectorEngine(EventEngine):
                     self.t = t
                     yield t
                     continue
-                if kind == 1:                       # scale_up: off-rail
+                if kind == 1:         # scale_up: TP never touches the rails
                     self.t = t
                     yield t
                     continue
                 new_phase = pi != prev_phase
                 if new_phase and pending_ready is not None:
+                    # §4.2: a provisioned reconfiguration is exposed only
+                    # past the window; split residue between control and
+                    # OCS time
                     exp = max(0.0, pending_ready - t)
                     exposed_c += min(exp, ctrl_async)
                     exposed_r += max(0.0, exp - ctrl_async)
                     t = max(t, pending_ready)
                     pending_ready = None
+                # Algorithm 1 on every rank (one batched plane call; the
+                # barrier completes at the last class write)
                 ev = plane.pre_comm_all(op, now=t)
                 write = ev.write if (ev.write is not None
                                      and ev.write.complete) else None
                 if write is not None:
                     n_writes += 1
                     if write.reconfigured:
+                        # on-demand: barrier + OCS latency fully exposed
                         n_reconfigs += 1
                         exposed_c += ctrl_sync
                         exposed_r += write.ack_time - t
                         t = write.ack_time + ctrl_sync
                     else:
+                        # lock-free write (suppressed / per-op PP)
                         exposed_c += PP_OP_CTRL
                         t += PP_OP_CTRL
+                # the collective itself, at the mode's bandwidth — on the
+                # §4.2 fallback ring at its dilated bandwidth (DESIGN.md §5)
                 start = t
                 t = start + (dur_f if plane.fallback_giant_ring else dur_h)
                 timeline.append(TimedOp(op, start - t0, t - t0))
                 prev_phase = pi
+                # Algorithm 2 on every rank (provisioning writes ride
+                # here, dispatched after the async control residue)
                 ev = plane.post_comm_all(op, now=t + ctrl_async)
                 write = ev.write if (ev.write is not None
                                      and ev.write.complete) else None
@@ -689,17 +520,26 @@ class VectorEngine(EventEngine):
                 raise ValueError(
                     "min_runtime_s on a zero-duration iteration "
                     f"(step_time={step_time!r}) would never terminate")
+        # plane telemetry counts the WHOLE plane lifetime (job
+        # registration + warmup + measured iterations); the "measured"
+        # sub-dict is the steady-state per-iteration delta
         tel = plane.telemetry()
         if measured is None:       # no captured steady cycle (fault path)
             measured = {k: tel[k] - tel0[k] for k in tel
                         if isinstance(tel[k], int)
                         and not isinstance(tel[k], bool)}
         tel["measured"] = measured
-        tel["calls"] = plane.call_stats()
+        tel["calls"] = plane.call_stats()   # perf tracking (BENCH json)
         self.result = SimResult(
             step_time, n_reconfigs, n_writes, exposed_r, exposed_c,
             timeline, engine="event" if plane.collapse else "event_full",
             telemetry=tel)
+
+    def run(self) -> SimResult:
+        for _ in self.events():
+            pass
+        assert self.result is not None
+        return self.result
 
 
 # ---------------------------------------------------------------------------
@@ -712,7 +552,7 @@ def _simulate_analytic(wl: TimedWorkload, params: SimParams) -> SimResult:
     n_ways = job.pp
     circuit = params.fabric_spec().circuit_switched
     ops = wl.scheduled_ops("phase_boundary", circuit=circuit)
-    table, phase_of = _phase_info(wl, "phase_boundary", circuit)
+    table, phase_of = wl.phase_info("phase_boundary", circuit=circuit)
 
     shares = _static_split(job) if params.mode == "oneshot" else {}
     reconf_total = params.ocs_latency + params.nic_linkup
@@ -738,7 +578,9 @@ def _simulate_analytic(wl: TimedWorkload, params: SimParams) -> SimResult:
     for op in ops:
         t += op.compute_before
         if op.scale == "mgmt":
-            t = _mgmt_op(op, t, 0.0, timeline)
+            dur = MGMT_LAT + op.bytes_per_gpu * 8 / (MGMT_GBPS * 1e9)
+            timeline.append(TimedOp(op, t, t + dur))
+            t += dur
             continue
         if op.scale == "scale_up":
             continue  # TP never touches the rails

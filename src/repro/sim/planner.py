@@ -34,8 +34,8 @@ dominance test: it neither saves nor condemns the cell.
 
 Everything is deterministic — the grid is a perf-gated BENCH record
 (``benchmarks/run.py --planner``) whose integer counters must match
-exactly across machines.  The two headline points the vectorized engine
-makes affordable (:func:`headline_points`) ride along: a 100k-GPU
+exactly across machines.  The two headline points the event engine's
+fast-forward makes affordable (:func:`headline_points`) ride along: a 100k-GPU
 single-job step and a 256-job week-long cluster trace, each in seconds.
 """
 from __future__ import annotations
@@ -414,14 +414,14 @@ def plan(cfg: PlannerConfig = PlannerConfig(), *,
 
 
 # ---------------------------------------------------------------------------
-# the two scale points the vectorized engine buys (DESIGN.md §12)
+# the two scale points the engine fast-forward buys (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
 
 def single_job_100k(gpu: str = "h200",
                     ocs_latency: float = 0.01) -> Dict[str, object]:
     """One 100,000-GPU training job (llama_80b, tp=8 x fsdp=6250 x pp=2)
-    through the vectorized engine — the paper's §7 scale extrapolated,
+    through the event engine — the paper's §7 scale extrapolated,
     in well under a second of wall clock."""
     from repro.configs.base import get_config
     t0 = time.perf_counter()

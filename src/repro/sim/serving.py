@@ -56,7 +56,7 @@ from repro.core import phases as ph
 from repro.core.fabric import FabricSpec, OCSArray
 from repro.core.orchestrator import PortAllocator, RailOrchestrator
 from repro.core.plane import ControlPlane
-from repro.sim.opus_sim import SHIM_MODE, SimParams, SimResult, VectorEngine
+from repro.sim.opus_sim import SHIM_MODE, EventEngine, SimParams, SimResult
 from repro.sim.traces import Request, TraceParams, make_trace
 from repro.sim.workload import GPUS, build_serving
 
@@ -312,12 +312,11 @@ class ServingFleet:
                            batch_slots=pool.batch_slots,
                            prompt_tokens=pool.ref_prompt_tokens,
                            calibration=self.params.calibration)
-        # replica steps are priced through the same vectorized core the
-        # training engine runs (DESIGN.md §12); a one/two-iteration
-        # serving step never fast-forwards, so the numbers are
-        # bit-identical to the per-op collapsed engine's
-        engine = VectorEngine(wl, self.params.sim_params(pool.mode),
-                              plane=plane, start=now)
+        # replica steps are priced through the same event engine the
+        # training jobs run (DESIGN.md §12); a one/two-iteration serving
+        # step never fast-forwards
+        engine = EventEngine(wl, self.params.sim_params(pool.mode),
+                             plane=plane, start=now)
         res = engine.run()
         rep = Replica(name, kind, pool, grant, plane, admitted=now,
                       ready=engine.t, result=res, busy_until=engine.t)

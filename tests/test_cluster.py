@@ -216,11 +216,11 @@ def test_single_job_cluster_is_bit_exact_with_single_job_engine(mode):
 
 
 def test_event_engine_generator_equals_run():
-    """Draining events() by hand is run(): the resumable form does not
-    perturb the arithmetic."""
+    """Draining events() by hand is the live walk's run() (a never-firing
+    injector): the resumable form does not perturb the arithmetic."""
     wl = build(SMALL, "a100")
     p = SimParams(mode="opus_prov", ocs_latency=0.01)
-    a = EventEngine(wl, p).run()
+    a = EventEngine(wl, p, ocs_fail=lambda attempt: False).run()
     eng = EventEngine(wl, p)
     clocks = list(eng.events())
     assert eng.result.step_time == a.step_time

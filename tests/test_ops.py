@@ -3,14 +3,16 @@ that migrate, defrag that acts, and a fleet you can diff.
 
 The recovery contract tested here is the tentpole: after a flap
 repairs, the plane is back on the REQUESTED topology (not the giant
-ring it demoted to), the replay cache re-promotes, the vectorized
-engine's fast-forward re-arms, and the next steady iteration's integer
-counters match a never-faulted run exactly on all three event engines.
+ring it demoted to), the replay cache re-promotes, the engine's
+fast-forward re-arms, and the next steady iteration's integer counters
+match a never-faulted run exactly: fast-forwarding, walked live, and on
+the uncollapsed plane.
 """
 import json
 import math
 import subprocess
 import sys
+from dataclasses import replace
 from pathlib import Path
 
 import pytest
@@ -25,8 +27,7 @@ from repro.sim.cluster import (ClusterJobSpec, ClusterParams, ClusterSim,
                                simulate_cluster)
 from repro.sim.ops import (DefragPolicy, DrainWindow, ScenarioEngine,
                            diff_twin, run_scenario, write_twin_jsonl)
-from repro.sim.opus_sim import (SHIM_MODE, EventEngine, SimParams,
-                                VectorEngine, simulate)
+from repro.sim.opus_sim import SHIM_MODE, EventEngine, SimParams, simulate
 from repro.sim.workload import build
 
 CFG = get_config("llama3_8b")
@@ -36,11 +37,25 @@ TINY = JobConfig(model=CFG.replace(n_layers=2), tp=2, fsdp=2, pp=1,
                  global_batch=16, seq_len=2048)      # 2 scale-out ranks
 P = SimParams(mode="opus_prov", ocs_latency=0.01)
 
+
+
+def _live(fm):
+    """An injector that keeps the engine walking every iteration live: a
+    faultable plane never fast-forwards before its fault horizon.  A
+    clean run gets one that never fires; a flap schedule gets an empty
+    outage window far past the run, which moves the horizon and nothing
+    else."""
+    if fm is None:
+        return lambda attempt: False
+    return replace(fm, flaps=fm.flaps + (
+        LinkFlap(rail=-1, start=1e9, duration=0.0),))
+
+
 ENGINES = {
-    "event": lambda wl, fm, n: VectorEngine(wl, P, ocs_fail=fm,
-                                            iterations=n),
-    "event_collapsed": lambda wl, fm, n: EventEngine(wl, P, ocs_fail=fm,
-                                                     iterations=n),
+    "event": lambda wl, fm, n: EventEngine(wl, P, ocs_fail=fm,
+                                           iterations=n),
+    "live": lambda wl, fm, n: EventEngine(wl, P, ocs_fail=_live(fm),
+                                          iterations=n),
     "event_full": lambda wl, fm, n: EventEngine(wl, P, ocs_fail=fm,
                                                 collapse=False,
                                                 iterations=n),
@@ -91,9 +106,9 @@ def test_pick_victim_deterministic():
 def test_short_flap_survives_within_retry_budget():
     wl = build(SMALL, "h200")
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=0.4),))
-    clean = VectorEngine(wl, P, iterations=8)
+    clean = EventEngine(wl, P, iterations=8)
     clean.run()
-    eng = VectorEngine(wl, P, ocs_fail=fm, iterations=8)
+    eng = EventEngine(wl, P, ocs_fail=fm, iterations=8)
     eng.run()
     fs = eng.plane.fault_stats()
     assert fs["n_retries"] >= 1
@@ -154,18 +169,19 @@ def test_recovery_bit_exact_counters_all_engines(name):
 def test_recovery_rearms_fast_forward():
     wl = build(SMALL, "h200")
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=5.0),))
-    eng = VectorEngine(wl, P, ocs_fail=fm, iterations=30)
+    eng = EventEngine(wl, P, ocs_fail=fm, iterations=30)
     eng.run()
     assert eng.plane.fault_stats()["n_recoveries"] == 1
     assert eng.fastforwarded_iterations > 0        # re-armed after repair
     # without recovery the demoted plane never fast-forwards (§4.2)
-    eng2 = VectorEngine(wl, P, ocs_fail=lambda attempt: True, iterations=30)
+    eng2 = EventEngine(wl, P, ocs_fail=lambda attempt: True, iterations=30)
     eng2.run()
     assert eng2.fastforwarded_iterations == 0
 
 
 def test_recovery_engine_parity():
-    """The recovered steady state agrees across all three engines."""
+    """The recovered steady state agrees fast-forwarding, walked live,
+    and on the uncollapsed plane."""
     wl = build(SMALL, "h200")
     fm = FaultModel(flaps=(LinkFlap(rail=-1, start=2.0, duration=5.0),))
     measured = {}
@@ -173,10 +189,12 @@ def test_recovery_engine_parity():
         eng = make(wl, fm, 30)
         eng.run()
         measured[name] = _ints(eng.result.telemetry["measured"])
-    assert measured["event"] == measured["event_collapsed"]
+        if name == "live":
+            assert eng.fastforwarded_iterations == 0
+    assert measured["event"] == measured["live"]
     # the full engine dispatches per rank; its equivalence-classed
     # counters still match
-    assert measured["event_collapsed"] == measured["event_full"]
+    assert measured["live"] == measured["event_full"]
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,10 @@ Four layers of coverage:
 
 * bit-identity — ``scheduler="phase_boundary"`` passed explicitly must
   reproduce the default path exactly (step time AND every integer
-  counter) across the paper configs x the backend axis x all three
-  event engines; the default scheduler is the committed-baseline
-  contract the perf gate enforces.
+  counter) across the paper configs x the backend axis, and the event
+  engine, its live walk and ``event_full`` agree under both schedulers;
+  the default scheduler is the committed-baseline contract the perf
+  gate enforces.
 * per-collective decomposition — round counts, variants, byte
   conservation and compute placement of the rewritten op stream.
 * the fabric still rules — radix holes on an OCS array and mid-round
@@ -22,7 +23,7 @@ from repro.core.fabric import CrossbarOCS, CrossSubSwitchError, FabricSpec
 from repro.core.phases import CommOp, JobConfig
 from repro.core.scheduler import (PerCollectiveScheduler,
                                   PhaseBoundaryScheduler, get_scheduler)
-from repro.sim.opus_sim import SimParams, simulate
+from repro.sim.opus_sim import EventEngine, SimParams, simulate
 from repro.sim.workload import build
 
 # the paper's dense Configs 1-2 plus two EP-heavy MoE shapes — the
@@ -97,12 +98,14 @@ def test_explicit_phase_boundary_is_bit_identical(workloads, jobkey, mode,
 
 @pytest.mark.parametrize("scheduler", ["phase_boundary", "per_collective"])
 def test_three_way_engine_parity(moe_wl, scheduler):
-    """event / event_collapsed / event_full agree bit-for-bit under BOTH
-    schedulers — the rewritten op stream is just a stream to them."""
+    """event / the live walk (a never-firing injector) / event_full
+    agree bit-for-bit under BOTH schedulers — the rewritten op stream is
+    just a stream to them."""
     p = SimParams(mode="opus_prov", ocs_latency=0.01, scheduler=scheduler)
     ref = simulate(moe_wl, p, engine="event")
-    for engine in ("event_collapsed", "event_full"):
-        _assert_identical(ref, simulate(moe_wl, p, engine=engine))
+    _assert_identical(ref, EventEngine(
+        moe_wl, p, ocs_fail=lambda attempt: False).run())
+    _assert_identical(ref, simulate(moe_wl, p, engine="event_full"))
 
 
 def test_analytic_engine_matches_event_on_default_path(workloads):

@@ -1,18 +1,19 @@
-"""Vectorized array-backed engine (DESIGN.md §12): bit-exact parity of
-the ``event`` (VectorEngine) / ``event_collapsed`` / ``event_full``
-engines over the paper configs, fast-forward integer exactness beyond
-the captured steady iteration, runtime-sized tenants, and engine
-invariance of the shared-rail cluster numbers."""
+"""The event engine's fast-forward (DESIGN.md §12): bit-exact parity of
+``engine="event"``, the live walk and ``engine="event_full"`` over the
+paper configs, fast-forward integer exactness beyond the captured steady
+iteration, runtime-sized tenants, and the shared-rail cluster numbers
+equal to an all-live cluster's.
+
+The live walk is the same engine on a plane with a fault injector that
+never fires: a faultable plane never fast-forwards."""
 import numpy as np
 import pytest
 
 from repro.configs.base import get_config
 from repro.core.phases import (JobConfig, build_phase_table,
                                iteration_schedule, phase_index_of)
-from repro.sim.cluster import (ClusterParams, ClusterSim, catalog_jobs,
-                               simulate_cluster)
-from repro.sim.opus_sim import (EventEngine, SimParams, VectorEngine,
-                                simulate)
+from repro.sim.cluster import ClusterParams, catalog_jobs, simulate_cluster
+from repro.sim.opus_sim import EventEngine, SimParams, simulate
 from repro.sim.workload import build
 
 LLAMA = get_config("llama3_8b")
@@ -42,18 +43,28 @@ def _params(mode):
     return SimParams(mode=mode, ocs_latency=0.01)
 
 
+def _never(attempt):
+    """A fault injector that never fires: the plane stays healthy, but
+    the engine walks every iteration live."""
+    return False
+
+
+def _live(wl, params, **kw):
+    return EventEngine(wl, params, ocs_fail=_never, **kw)
+
+
 @pytest.mark.parametrize("job", PAPER_CONFIGS,
                          ids=[f"cfg{i}" for i in range(len(PAPER_CONFIGS))])
 @pytest.mark.parametrize("mode", MODES)
 def test_three_way_parity(job, mode):
-    """engine="event" (vectorized), "event_collapsed", and
-    "event_full" agree bit-exactly: step time, every counter, every
-    measured delta, the whole timeline."""
+    """engine="event", the live walk, and "event_full" agree
+    bit-exactly: step time, every counter, every measured delta, the
+    whole timeline."""
     wl = build(job, "h200")
     vec = simulate(wl, _params(mode))
-    col = simulate(wl, _params(mode), engine="event_collapsed")
+    live = _live(wl, _params(mode)).run()
     full = simulate(wl, _params(mode), engine="event_full")
-    for other in (col, full):
+    for other in (live, full):
         assert vec.step_time == other.step_time
         assert vec.n_reconfigs == other.n_reconfigs
         assert vec.n_topo_writes == other.n_topo_writes
@@ -67,41 +78,39 @@ def test_three_way_parity(job, mode):
 @pytest.mark.parametrize("mode", ("opus", "opus_prov"))
 def test_parity_under_persistent_fault_demotion(mode):
     """A persistently failing OCS demotes the job to the §4.2 giant-ring
-    fallback; the vectorized engine must take the demotion live (never
-    fast-forward a faulted plane) and stay bit-exact."""
+    fallback; the engine must take the demotion live (never fast-forward
+    a faulted plane) and stay bit-exact with the uncollapsed plane."""
     job = PAPER_CONFIGS[1]
     wl = build(job, "h200")
-    results = [simulate(wl, _params(mode), engine=eng,
-                        ocs_fail=lambda attempt: True)
-               for eng in ("event", "event_collapsed", "event_full")]
-    vec, col, full = results
+    vec, full = [simulate(wl, _params(mode), engine=eng,
+                          ocs_fail=lambda attempt: True)
+                 for eng in ("event", "event_full")]
     assert vec.telemetry["fallback_giant_ring"]
-    for other in (col, full):
-        assert vec.step_time == other.step_time
-        assert vec.timeline == other.timeline
-        assert _tel_no_calls(vec.telemetry) == _tel_no_calls(
-            other.telemetry)
+    assert vec.step_time == full.step_time
+    assert vec.timeline == full.timeline
+    assert _tel_no_calls(vec.telemetry) == _tel_no_calls(full.telemetry)
     # demoted planes never capture a replay schedule to fast-forward
-    engine = VectorEngine(wl, _params(mode),
-                          ocs_fail=lambda attempt: True, iterations=6)
+    engine = EventEngine(wl, _params(mode),
+                         ocs_fail=lambda attempt: True, iterations=6)
     engine.run()
     assert engine.fastforwarded_iterations == 0
 
 
 @pytest.mark.parametrize("mode", ("opus", "opus_prov", "oneshot"))
 def test_fastforward_integer_exactness(mode):
-    """Beyond the captured steady iteration the vectorized engine jumps
-    k iterations in one array op: every integer counter must land
-    EXACTLY where the live walk lands, and the clock within float
-    accumulation noise."""
+    """Beyond the captured steady iteration the engine jumps k
+    iterations in one array op: every integer counter must land EXACTLY
+    where the live walk lands, and the clock within float accumulation
+    noise."""
     job = PAPER_CONFIGS[0]
     wl = build(job, "h200")
     iters = 9
-    vec = VectorEngine(wl, _params(mode), iterations=iters)
+    vec = EventEngine(wl, _params(mode), iterations=iters)
     vec.run()
-    live = EventEngine(wl, _params(mode), iterations=iters)
+    live = _live(wl, _params(mode), iterations=iters)
     live.run()
     assert vec.fastforwarded_iterations > 0
+    assert live.fastforwarded_iterations == 0
     v_tel, l_tel = vec.result.telemetry, live.result.telemetry
     for key, lv in _tel_no_calls(l_tel).items():
         vv = v_tel[key]
@@ -122,7 +131,7 @@ def test_fastforward_integer_exactness(mode):
 def test_fastforward_and_live_iterations_partition():
     job = PAPER_CONFIGS[0]
     wl = build(job, "h200")
-    engine = VectorEngine(wl, _params("opus_prov"), iterations=12)
+    engine = EventEngine(wl, _params("opus_prov"), iterations=12)
     engine.run()
     # the warmup and the captured first replayed iteration run live;
     # every steady iteration after that fast-forwards
@@ -132,8 +141,8 @@ def test_fastforward_and_live_iterations_partition():
 def test_min_runtime_fastforwards_to_target():
     job = PAPER_CONFIGS[0]
     wl = build(job, "h200")
-    engine = VectorEngine(wl, _params("opus_prov"),
-                          min_runtime_s=3600.0, start=5.0)
+    engine = EventEngine(wl, _params("opus_prov"),
+                         min_runtime_s=3600.0, start=5.0)
     engine.run()
     step = engine.result.step_time
     assert engine.t >= 5.0 + 3600.0
@@ -142,16 +151,16 @@ def test_min_runtime_fastforwards_to_target():
     assert engine.fastforwarded_iterations > 100
 
 
-def test_cluster_numbers_are_engine_invariant(monkeypatch):
+def test_cluster_numbers_are_engine_invariant():
     """The shared-rail cluster point produces the same summary (every
-    counter exact, every float identical) whether tenants run on the
-    vectorized core or the per-op collapsed engine."""
+    counter exact, every float identical) whether tenants may fast-
+    forward or every tenant walks live (a never-firing injector each)."""
     specs = catalog_jobs(4, 16, mean_gap=0.5)
     params = ClusterParams(n_ports=64, policy="contiguous",
                            ocs_latency=0.01)
     vec = simulate_cluster(specs, params).summary()
-    monkeypatch.setattr(ClusterSim, "ENGINE_CLS", EventEngine)
-    live = simulate_cluster(specs, params).summary()
+    live = simulate_cluster(specs, params, ocs_fail_by_job={
+        s.name: _never for s in specs}).summary()
     assert vec == live
 
 
@@ -204,8 +213,8 @@ def test_min_runtime_rejects_zero_length_iterations():
     wl = build(job, "h200")
     empty = wl.__class__(job=wl.job, gpu=wl.gpu, ops=[],
                          t_fwd_layer=0.0, t_bwd_layer=0.0)
-    engine = VectorEngine(empty, _params("opus_prov"),
-                          min_runtime_s=10.0)
+    engine = EventEngine(empty, _params("opus_prov"),
+                         min_runtime_s=10.0)
     with pytest.raises(ValueError):
         engine.run()
 
@@ -219,11 +228,11 @@ def test_vector_engine_reports_event_engine_name():
 
 
 def test_simulate_default_engine_is_vectorized():
-    """The default engine path goes through VectorEngine (with zero
+    """The default engine path goes through EventEngine (with zero
     fast-forward at the committed 2-iteration shape, hence bit-exact
     BENCH records)."""
     wl = build(PAPER_CONFIGS[0], "h200")
-    engine = VectorEngine(wl, _params("opus_prov"))
+    engine = EventEngine(wl, _params("opus_prov"))
     engine.run()
     assert engine.fastforwarded_iterations == 0
     assert engine.result.step_time == simulate(
