@@ -67,8 +67,11 @@ def decode_attention(q, k_cache, v_cache, valid_mask, layer, *,
     q [B,1,H,dh], caches [L,B,KV,dh,C], valid [B,C], layer an int32
     scalar.  The kernel reads the layer's tiles in place in the stack, in
     the stored layout: no slice or relayout of the cache around the call.
+    It reads only the cache blocks of each row that hold a valid slot
+    (``decode_attention.valid_blocks``); a row with none gives zeros.
     """
-    mode = _dispatch("decode_attention", " (stacked, layer operand)")
+    mode = _dispatch("decode_attention",
+                     " (stacked, layer operand, valid blocks)")
     with scope("attn_decode"):
         if mode in ("pallas", "pallas_interpret"):
             from repro.kernels import decode_attention as da

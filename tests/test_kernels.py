@@ -10,6 +10,7 @@ import pytest
 from repro.configs.base import get_config
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as pl_decode
+from repro.kernels.decode_attention import valid_blocks
 from repro.kernels.flash_attention import flash_attention as pl_flash
 from repro.kernels.ssd_scan import ssd as pl_ssd
 from repro.models.attention import _repeat_kv, make_mask, sdpa
@@ -127,6 +128,94 @@ def test_decode_kernel_reads_layer_in_stack(h, kv, dh, c, layer):
                     interpret=True)
     want = ref.decode_attention(q, kc[layer], vc[layer], valid)
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def _rows(c, *spans):
+    """A [B,C] mask, row r valid on spans[r], a list of [lo, hi) ranges."""
+    m = np.zeros((len(spans), c), bool)
+    for r, row in enumerate(spans):
+        for lo, hi in row:
+            m[r, lo:hi] = True
+    return m
+
+
+# (capacity, block_k, layer of a 3-layer stack, mask [B,C]); at C 256 and
+# block_k 64 the blocks are [0,64), [64,128), [128,192), [192,256)
+_SKIP_CASES = {
+    "prefix_in_first_block": (256, 64, 0, _rows(256, [(0, 20)], [(0, 1)])),
+    "prefix_ends_in_block_0": (256, 64, 0, _rows(256, [(0, 64)], [(0, 64)])),
+    "prefix_ends_in_block_1": (256, 64, 0, _rows(256, [(0, 100)], [(0, 65)])),
+    "prefix_ends_in_block_2": (256, 64, 0, _rows(256, [(0, 170)], [(0, 192)])),
+    "prefix_ends_in_block_3": (256, 64, 0, _rows(256, [(0, 250)], [(0, 256)])),
+    "rows_of_different_lengths": (256, 64, 0, _rows(
+        256, [(0, 10)], [(0, 130)], [(0, 256)], [(0, 64)])),
+    "empty_blocks_between": (256, 64, 0, _rows(
+        256, [(0, 10), (200, 211)], [(60, 70), (255, 256)])),
+    "ring_window_wraps": (256, 64, 0, _rows(
+        256, [(220, 256), (0, 64)], [(100, 200)], [(250, 256), (0, 94)])),
+    "stacked_middle_layer": (256, 64, 1, _rows(
+        256, [(0, 130)], [(0, 10), (130, 140)])),
+    "ragged_capacity": (300, 128, 2, _rows(
+        300, [(0, 5)], [(0, 260)], [(0, 300)], [(290, 300)])),
+}
+
+
+@pytest.mark.parametrize("case", list(_SKIP_CASES.values()),
+                         ids=list(_SKIP_CASES))
+def test_decode_kernel_reads_only_valid_blocks(case):
+    """Flash-decode visits only the blocks that hold a valid slot, and
+    equals the oracle, which reads every block, on each mask."""
+    c, block_k, layer, valid = case
+    b, h, kv, dh = valid.shape[0], 8, 2, 64
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
+    kc = jax.random.normal(ks[1], (3, b, kv, dh, c)) * 0.5
+    vc = jax.random.normal(ks[2], (3, b, kv, dh, c)) * 0.5
+    got = pl_decode(q, kc, vc, jnp.asarray(valid), jnp.int32(layer),
+                    block_k=block_k, interpret=True)
+    want = ref.decode_attention(q, kc[layer], vc[layer], jnp.asarray(valid))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_decode_kernel_fully_masked_row_is_zero():
+    """A row with no valid slot lists no block: its output is zeros, and
+    the rows around it still equal the oracle."""
+    c, b, h, kv, dh = 256, 3, 8, 2, 64
+    valid = _rows(c, [(0, 100)], [], [(64, 256)])
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, 1, h, dh)) * 0.5
+    kc = jax.random.normal(ks[1], (1, b, kv, dh, c)) * 0.5
+    vc = jax.random.normal(ks[2], (1, b, kv, dh, c)) * 0.5
+    got = pl_decode(q, kc, vc, jnp.asarray(valid), 0, block_k=64,
+                    interpret=True)
+    want = ref.decode_attention(q, kc[0], vc[0], jnp.asarray(valid))
+    np.testing.assert_array_equal(got[1], np.zeros_like(got[1]))
+    np.testing.assert_allclose(got[::2], want[::2], atol=1e-5)
+
+
+@pytest.mark.parametrize("case", list(_SKIP_CASES.values()),
+                         ids=list(_SKIP_CASES))
+def test_valid_blocks_lists_blocks_with_a_valid_slot(case):
+    c, block_k, _, valid = case
+    count, blocks = valid_blocks(jnp.asarray(valid), block_k)
+    nk = -(-c // block_k)
+    assert blocks.shape == (valid.shape[0], nk)
+    for r, row in enumerate(valid):
+        hit = [j for j in range(nk)
+               if row[j * block_k:(j + 1) * block_k].any()]
+        assert int(count[r]) == len(hit)
+        assert list(np.asarray(blocks[r, :len(hit)])) == hit
+        assert sorted(np.asarray(blocks[r])) == list(range(nk))
+
+
+@pytest.mark.parametrize("n_valid,want", [(545, 1), (1500, 2), (2240, 3)])
+def test_valid_blocks_at_the_decode_cells_shapes(n_valid, want):
+    """Capacity 4096 in blocks of 1024, as the decode cells run it."""
+    valid = jnp.broadcast_to(jnp.arange(4096) < n_valid, (16, 4096))
+    count, blocks = valid_blocks(valid, 1024)
+    np.testing.assert_array_equal(count, want)
+    np.testing.assert_array_equal(
+        blocks[:, :want], np.broadcast_to(np.arange(want), (16, want)))
 
 
 def _naive_ssd(x, dt, a, bm, cm):

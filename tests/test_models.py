@@ -109,7 +109,8 @@ def test_decode_kernel_path_matches_ref(monkeypatch, caplog):
         return jnp.stack(outs, 1), state
 
     got, got_state = run("pallas_interpret")
-    assert ("decode_attention: interpret (stacked, layer operand)"
+    assert ("decode_attention: interpret "
+            "(stacked, layer operand, valid blocks)"
             in caplog.messages), caplog.messages
     want, want_state = run("ref")
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
